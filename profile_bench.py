@@ -1,8 +1,7 @@
 """Profile the 1k-host 3-tier bench under --scheduler=tpu (CPU backend)."""
 import cProfile, pstats, sys, os, io
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from shadow_tpu.utils.platform import force_cpu
-force_cpu()
+os.environ["JAX_PLATFORMS"] = "cpu"
 import bench
 from shadow_tpu.core.manager import Manager
 

@@ -17,9 +17,6 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-from shadow_tpu.utils.platform import honor_platform_env  # noqa: E402
-
-honor_platform_env(default="cpu")
 
 from shadow_tpu.core.config import ConfigOptions  # noqa: E402
 from shadow_tpu.core.manager import run_simulation  # noqa: E402

@@ -10,8 +10,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 if not os.environ.get("PROBE_REAL_TPU"):
-    from shadow_tpu.utils.platform import force_cpu
-    force_cpu()
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
 from shadow_tpu.core.config import ConfigOptions
 from shadow_tpu.core.manager import Manager

@@ -9,8 +9,7 @@ import time
 os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=8")
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-from shadow_tpu.utils.platform import force_cpu
-force_cpu()
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 from shadow_tpu.core.config import ConfigOptions
 from shadow_tpu.core.manager import run_simulation

@@ -24,8 +24,7 @@ if not os.environ.get("PROBE_REAL_TPU"):
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = \
             (flags + " --xla_force_host_platform_device_count=8").strip()
-    from shadow_tpu.utils.platform import force_cpu
-    force_cpu()
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
 from shadow_tpu.core.config import ConfigOptions  # noqa: E402
 from shadow_tpu.core.manager import Manager  # noqa: E402

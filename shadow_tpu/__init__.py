@@ -18,9 +18,15 @@ Layering (mirrors reference layer map, SURVEY.md section 1):
 """
 
 # Simulation times are 64-bit nanosecond counts; JAX must not silently
-# truncate them to 32 bits anywhere on the device path.
-import jax
+# truncate them to 32 bits anywhere on the device path.  The flag goes
+# through the environment, which JAX reads when it is imported, so that
+# importing the package does not import JAX (a sweep runner's parent
+# process never touches it); a JAX imported earlier is updated directly.
+import os
+import sys
 
-jax.config.update("jax_enable_x64", True)
+os.environ["JAX_ENABLE_X64"] = "1"
+if "jax" in sys.modules:
+    sys.modules["jax"].config.update("jax_enable_x64", True)
 
 __version__ = "0.1.0"

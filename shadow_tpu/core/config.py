@@ -195,8 +195,11 @@ class ExperimentalConfig:
     # round 6, reproduced with MALLOC_CHECK_ on the CPU backend).  "on"
     # re-lands donation behind a compile-cache-safe guard: the span
     # runners donate ONLY when no persistent compilation cache is
-    # configured (jax_compilation_cache_dir unset), and fall back to
-    # undonated dispatch otherwise — never the corrupting combination.
+    # in use, and fall back to undonated dispatch otherwise — never the
+    # corrupting combination.  The entry points (CLI, bench.py,
+    # chip_smoke.py, __graft_entry__.py) always keep a persistent cache
+    # (utils/compile_cache.py), so there "on" never donates; only a
+    # process with the cache off (the tests) does.
     tpu_donate_buffers: str = "off"
     # Overlapped span pipeline (docs/OBSERVABILITY.md "Overlapped
     # pipeline"): "on" double-buffers the device-span dispatch — after
@@ -215,7 +218,9 @@ class ExperimentalConfig:
     # head classification of both span families through pallas
     # kernels (interpret mode on the CPU backend, so tier-1 still
     # runs them); "off" keeps the inline lax forms.  Integer-exact
-    # either way — byte identity is gated, not assumed.
+    # either way — byte identity is gated, not assumed.  On a TPU,
+    # Mosaic refuses the kernels' int64 lanes, so "on" fails there
+    # with the compiler's message (ROADMAP A4).
     pallas_queue_kernels: str = "off"
     # Speculative-window heuristics for the device-span router
     # (core/manager.py), promoted from hard-coded constants:

@@ -2020,6 +2020,7 @@ class Manager:
             dispatch["packets_overflowed"] = prop.packets_overflowed
             dispatch["exchange_wall_s"] = round(
                 getattr(prop, "exchange_wall_ns", 0) / 1e9, 6)
+            dispatch["state_devices"] = prop.state_devices
         fn_cache = {}
         for family, runner in (("phold", getattr(self, "_dev_span",
                                                  None)),
@@ -2044,6 +2045,7 @@ class Manager:
                                             0),
                     "exchange_grows": getattr(runner, "exch_grows",
                                               0),
+                    "state_devices": runner.state_devices,
                     # Device-kernel observatory wall side (ISSUE 15):
                     # dispatch wall, the speculative-window rollback
                     # ledger (aborted dispatch wall + forced

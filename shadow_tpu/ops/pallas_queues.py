@@ -73,7 +73,9 @@ def codel_head_ref(jnp, target_ns, mtu, pop, none, now, enq,
 def _interpret(jax) -> bool:
     """Compiled pallas needs a real accelerator backend; the CPU
     backend runs the same kernel body through the pallas interpreter
-    so tier-1 exercises the kernel path without TPU hardware."""
+    so tier-1 exercises the kernel path without TPU hardware.  On a
+    TPU the kernels are compiled, never interpreted — and Mosaic
+    refuses their int64 lanes today (tests/test_tpu_compile.py)."""
     return jax.default_backend() == "cpu"
 
 

@@ -1829,7 +1829,7 @@ class PholdSpanRunner(SpanMeshMixin):
         Residency: while the engine's state_epoch is unchanged since
         our last import (nothing but this runner touched host state),
         the previous span's device-resident output is reused directly
-        and the export+conversion leg of the dispatch tunnel is
+        and the export+conversion leg of the dispatch is
         skipped; ANY other engine call in between makes the resident
         copy stale and forces a fresh export (never silent reuse).
 
@@ -1895,6 +1895,7 @@ class PholdSpanRunner(SpanMeshMixin):
                     start, stop, limit, runahead, mr)
             (st_out, next_start, ra, rounds, busy_rounds, packets,
              busy_end, span_iters) = out
+            self.state_devices = len(st_out["now"].sharding.device_set)
             st_np = {k: np.asarray(v) for k, v in st_out.items()}
             code = int(st_np["abort_code"])
             # The first dispatch THROUGH A GIVEN BUILT FN pays

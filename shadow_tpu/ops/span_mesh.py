@@ -10,7 +10,6 @@ provide `self.mesh` and `self._H`.
 
 from __future__ import annotations
 
-import os
 import sys
 import time
 
@@ -43,13 +42,14 @@ def donation_cache_safe() -> bool:
     compilation cache corrupts the glibc heap on deserialization-hit
     runs, so `experimental.tpu_donate_buffers: on` donates ONLY when
     no persistent cache is configured — never the corrupting
-    combination.  Checked once per kernel build (the cache dir is
-    process-static in practice)."""
+    combination.  Every entry point keeps a persistent cache
+    (utils/compile_cache.py), so there `on` never donates; only a
+    process with the cache turned off does.  Checked once per kernel
+    build (the cache dir is process-static in practice)."""
     global _donate_warned
     import jax
-    cache_dir = (getattr(jax.config, "jax_compilation_cache_dir", None)
-                 or os.environ.get("JAX_COMPILATION_CACHE_DIR"))
-    if cache_dir:
+    cache_dir = jax.config.jax_compilation_cache_dir
+    if cache_dir and jax.config.jax_enable_compilation_cache:
         if not _donate_warned:
             _donate_warned = True
             print("[shadow-tpu] tpu_donate_buffers=on ignored: a "
@@ -73,6 +73,9 @@ class SpanMeshMixin:
     # overflow is an attributed capacity abort, never truncation).
     exchange_cap = 1 << 12
     exch_grows = 0
+    # Distinct devices holding the last dispatch's state (a sharded
+    # mesh must spread it, not stack it on the first device).
+    state_devices = 0
 
     @property
     def n_shards(self) -> int:
@@ -112,7 +115,6 @@ class SpanMeshMixin:
     export_bytes = 0         # codec bytes engine -> host, cumulative
     import_bytes = 0         # codec bytes host -> engine, cumulative
     _aot = None              # fn ids whose cost this runner logged
-    _aot_off = False         # AOT path disabled after a failure
     kernel_costs = None      # Compiled.cost_analysis() per built fn
 
     # ---- Overlapped span pipeline (ISSUE 16) ------------------------
@@ -162,11 +164,7 @@ class SpanMeshMixin:
         finished, so the flush->land gap can be attributed as device
         idle honestly (ready_at_flush False keeps it a lower bound)."""
         spec["epoch"] = self.engine.state_epoch()
-        try:
-            spec["ready_at_flush"] = bool(
-                spec["out"][0]["abort_code"].is_ready())
-        except Exception:
-            spec["ready_at_flush"] = False
+        spec["ready_at_flush"] = spec["out"][0]["abort_code"].is_ready()
         spec["t_flush"] = time.perf_counter_ns()  # shadow-lint: allow[wall-clock] dispatch attribution (metrics.wall)
         self._inflight = spec
 
@@ -271,35 +269,28 @@ class SpanMeshMixin:
         _FN_CACHE entry (keyed on the cached fn's identity, which the
         never-evicting cache pins) so a later Manager's runner reuses
         it exactly like the jit call cache — warm runs stay warm.
-        Any AOT failure falls back to plain jit dispatch permanently —
-        attribution degrades, correctness never."""
-        if not self.kern_wall or self.mesh is not None \
-                or self._aot_off:
+        A lowering or compile error propagates: it is the compiler's
+        verdict on the kernel, and plain jit would only hit it again."""
+        if not self.kern_wall or self.mesh is not None:
             return fn(*args)
         if self._aot is None:
             self._aot = set()   # fn ids whose cost this runner logged
             self.kernel_costs = []
         ent = _AOT_CACHE.get(id(fn))
         if ent is None:
-            try:
-                t0 = time.perf_counter_ns()  # shadow-lint: allow[wall-clock] dispatch attribution (metrics.wall)
-                lowered = fn.lower(*args)
-                t1 = time.perf_counter_ns()  # shadow-lint: allow[wall-clock] dispatch attribution (metrics.wall)
-                comp = lowered.compile()
-                t2 = time.perf_counter_ns()  # shadow-lint: allow[wall-clock] dispatch attribution (metrics.wall)
-                cost = comp.cost_analysis()
-                if isinstance(cost, (list, tuple)):
-                    cost = cost[0] if cost else {}
-                ent = _AOT_CACHE[id(fn)] = (comp, {
-                    "flops": float(cost.get("flops", 0.0)),
-                    "bytes_accessed": float(
-                        cost.get("bytes accessed", 0.0)),
-                    "trace_wall_s": round((t1 - t0) / 1e9, 3),
-                    "compile_wall_s": round((t2 - t1) / 1e9, 3),
-                })
-            except Exception:
-                self._aot_off = True
-                return fn(*args)
+            t0 = time.perf_counter_ns()  # shadow-lint: allow[wall-clock] dispatch attribution (metrics.wall)
+            lowered = fn.lower(*args)
+            t1 = time.perf_counter_ns()  # shadow-lint: allow[wall-clock] dispatch attribution (metrics.wall)
+            comp = lowered.compile()
+            t2 = time.perf_counter_ns()  # shadow-lint: allow[wall-clock] dispatch attribution (metrics.wall)
+            cost = comp.cost_analysis()
+            ent = _AOT_CACHE[id(fn)] = (comp, {
+                "flops": float(cost.get("flops", 0.0)),
+                "bytes_accessed": float(
+                    cost.get("bytes accessed", 0.0)),
+                "trace_wall_s": round((t1 - t0) / 1e9, 3),
+                "compile_wall_s": round((t2 - t1) / 1e9, 3),
+            })
         if id(fn) not in self._aot:
             self._aot.add(id(fn))
             self.kernel_costs.append(dict(ent[1]))

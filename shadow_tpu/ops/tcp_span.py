@@ -2677,6 +2677,7 @@ class TcpSpanRunner(SpanMeshMixin):
                     start, stop, limit, runahead, mr)
             (st_out, next_start, ra, rounds, busy_rounds, packets,
              busy_end, span_iters) = out
+            self.state_devices = len(st_out["now"].sharding.device_set)
             st_np = {k: np.asarray(v) for k, v in st_out.items()}
             code = int(st_np["abort_code"])
             # First dispatch through a given built fn pays trace+XLA

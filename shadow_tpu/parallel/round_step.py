@@ -11,9 +11,10 @@ across devices on a `jax.sharding.Mesh` axis:
 - packets are exchanged to their destination shard with
   `lax.all_to_all` over the ICI (the device-resident replacement for
   the reference's locked per-host event queues, worker.rs:597-607);
-- the conservative barrier's global min-next-event-time is a
-  `lax.pmin` over the mesh axis (replacing manager.rs:447-487's
-  thread-reduction).
+- the conservative barrier's global min-next-event-time is a min
+  over the mesh axis (an `all_gather` of the shards' minima: the TPU
+  compiler reduces 64-bit values only by sum), replacing
+  manager.rs:447-487's thread-reduction.
 
 The exchange uses fixed per-shard-pair capacity (static shapes: XLA
 requirement); overflow falls back to host-side delivery, which only
@@ -54,23 +55,27 @@ def build_sharded_round_step(mesh, latency_ns: np.ndarray,
       overflow : bool[S, B]  kept but exceeded the exchange capacity
       reachable, lossy : bool[S, B]  drop diagnostics for tracing
       recv_idx, recv_time : exchanged packet index/time per source shard
-      barrier_min : int64[S] global min next event (pmin over shards)
+      barrier_min : int64[S] global min next event (min over shards)
       min_latency : int64[S] global min kept latency (dynamic runahead)
     """
     import jax
     import jax.numpy as jnp
     from jax import lax
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
 
     lat = jnp.asarray(latency_ns, dtype=jnp.int64)
     thr = jnp.asarray(thresholds, dtype=jnp.int64)
     key0 = jnp.uint32(k0)
     key1 = jnp.uint32(k1)
     n_shards = mesh.shape[HOST_AXIS]
+
+    def _pmin_i64(x):
+        # The global min over the mesh axis.  The TPU compiler lowers
+        # only sum all-reduces of 64-bit values, so the shards' minima
+        # are gathered (S scalars) and reduced locally: the same value
+        # as lax.pmin.
+        return jnp.min(lax.all_gather(x, HOST_AXIS))
 
     def shard_fn(src_node, dst_node, dst_shard, src_host, pkt_seq, t_send,
                  is_ctl, valid, host_next_event, window_end, bootstrap_end):
@@ -126,11 +131,11 @@ def build_sharded_round_step(mesh, latency_ns: np.ndarray,
         local_min = jnp.minimum(
             jnp.min(host_next_event),
             jnp.min(jnp.where(keep, deliver, _I64_MAX)))
-        barrier_min = lax.pmin(local_min, HOST_AXIS)
+        barrier_min = _pmin_i64(local_min)
         # Dynamic-runahead feedback: smallest latency any *delivered*
         # packet used this round, reduced globally (runahead.rs:61).
-        min_latency = lax.pmin(
-            jnp.min(jnp.where(keep, latency, _I64_MAX)), HOST_AXIS)
+        min_latency = _pmin_i64(
+            jnp.min(jnp.where(keep, latency, _I64_MAX)))
 
         return (deliver[None], keep[None], overflow[None], reachable[None],
                 lossy[None], recv_idx[None], recv_time[None],

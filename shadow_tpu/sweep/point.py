@@ -129,8 +129,6 @@ def main(argv=None) -> int:
         print("usage: python -m shadow_tpu.sweep.point TASK.json",
               file=sys.stderr)
         return 2
-    from shadow_tpu.utils.platform import honor_platform_env
-    honor_platform_env()
     with open(argv[0]) as f:
         task = json.load(f)
     return run_point(task)

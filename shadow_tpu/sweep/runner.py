@@ -4,7 +4,10 @@ post-ramp checkpoint.
 
 Cold path (the default): one `python -m shadow_tpu.sweep.point`
 subprocess per point, each with its own data directory and the spec's
-per-point wall limit.
+per-point wall limit.  Points run one at a time and the runner itself
+never imports JAX, so each point's process may hold the accelerator
+(a chip belongs to one process at a time); JAX_PLATFORMS, when set,
+passes through to the points unchanged.
 
 Warm path (`warm_start: {at_ms: N}`): points are grouped by their
 fork-group key (sweep/spec.expand — everything but the fork-safe
@@ -46,12 +49,6 @@ class PointFailure(RuntimeError):
 RETRY_BACKOFF_S = 2.0
 
 
-def _point_env() -> dict:
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    return env
-
-
 def _run_sub(task: dict, task_path: str, log_path: str,
              time_limit_s: float) -> None:
     with open(task_path, "w") as f:
@@ -62,7 +59,7 @@ def _run_sub(task: dict, task_path: str, log_path: str,
                 [sys.executable, "-m", "shadow_tpu.sweep.point",
                  task_path],
                 stdout=log, stderr=subprocess.STDOUT,
-                env=_point_env(), timeout=time_limit_s,
+                timeout=time_limit_s,
                 cwd=os.path.dirname(os.path.dirname(
                     os.path.dirname(os.path.abspath(__file__)))))
         except subprocess.TimeoutExpired:

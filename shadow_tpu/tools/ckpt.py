@@ -354,12 +354,8 @@ def main(argv=None) -> int:
                          "real binaries (the ./setup managed target)")
     args = ap.parse_args(argv)
     if args.smoke_managed:
-        from shadow_tpu.utils.platform import honor_platform_env
-        honor_platform_env()
         return smoke_managed(args.smoke_managed)
     if args.smoke:
-        from shadow_tpu.utils.platform import honor_platform_env
-        honor_platform_env()
         return smoke(args.hosts)
     ap.print_usage(sys.stderr)
     print("ckpt: a subcommand (info/verify/diff) or --smoke is "

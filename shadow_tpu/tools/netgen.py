@@ -164,7 +164,8 @@ def phold_yaml(n_hosts: int, n_init: int = 3,
                seed: int = 13, scheduler: str = "serial",
                device_spans: str | None = None,
                bandwidth: str = "1 Gbit", latency: str = "5 ms",
-               peers_per_host: int | None = None) -> str:
+               peers_per_host: int | None = None,
+               experimental_extra: dict | None = None) -> str:
     """Classic PHOLD (ref: src/test/phold): every host one LP bouncing
     messages to pseudo-random peers after pseudo-exponential holds.
     peers_per_host bounds each LP's peer list to its next-k ring
@@ -183,6 +184,8 @@ def phold_yaml(n_hosts: int, n_init: int = 3,
     exp = [f"  scheduler: {scheduler}"]
     if device_spans is not None:
         exp.append(f"  tpu_device_spans: {device_spans}")
+    for k, v in (experimental_extra or {}).items():
+        exp.append(f"  {k}: {v}")
     gml = (f'graph [ node [ id 0 host_bandwidth_down "{bandwidth}" '
            f'host_bandwidth_up "{bandwidth}" ] '
            f'edge [ source 0 target 0 latency "{latency}" ] ]')
@@ -198,7 +201,8 @@ def mesh_family_yaml(n_hosts: int, count: int = 30, size: int = 400,
                      loss: float = 0.02, latency: str = "10 ms",
                      sbuf: str = "8 KiB", seed: int = 29,
                      stop_time: str = "30s", scheduler: str = "serial",
-                     device_spans: str | None = None) -> str:
+                     device_spans: str | None = None,
+                     experimental_extra: dict | None = None) -> str:
     """Paced udp-mesh: every host ONE udp-mesh process (main sink +
     sender thread over a shared bound socket), bandwidth-paced so the
     sim spans many windows — the device-span mesh-family workload
@@ -216,6 +220,8 @@ def mesh_family_yaml(n_hosts: int, count: int = 30, size: int = 400,
            f"  socket_send_buffer: {sbuf}"]
     if device_spans is not None:
         exp.append(f"  tpu_device_spans: {device_spans}")
+    for k, v in (experimental_extra or {}).items():
+        exp.append(f"  {k}: {v}")
     loss_s = f" packet_loss {loss}" if loss else ""
     gml = (f'graph [ node [ id 0 host_bandwidth_down "{bw_down}" '
            f'host_bandwidth_up "{bw_up}" ] '
@@ -233,7 +239,9 @@ def tcp_stream_yaml(n_hosts: int, n_servers: int | None = None,
                     bw_up: str = "50 Mbit", stop_time: str = "4s",
                     seed: int = 11, scheduler: str = "serial",
                     device_spans: str | None = None,
-                    tcp: dict | None = None) -> str:
+                    tcp: dict | None = None,
+                    experimental_extra: dict | None = None,
+                    bootstrap_end_time: str | None = None) -> str:
     """Fixed-connection TCP streaming tier: every client opens ONE
     connection (count=1, synchronized starts, no accept churn) and the
     transfer is sized to still be streaming at stop_time — so after the
@@ -269,7 +277,11 @@ def tcp_stream_yaml(n_hosts: int, n_servers: int | None = None,
            "  socket_recv_autotune: false"]
     if device_spans is not None:
         exp.append(f"  tpu_device_spans: {device_spans}")
-    return (f"general: {{ stop_time: {stop_time}, seed: {seed} }}\n"
+    for k, v in (experimental_extra or {}).items():
+        exp.append(f"  {k}: {v}")
+    boot = (f", bootstrap_end_time: {bootstrap_end_time}"
+            if bootstrap_end_time else "")
+    return (f"general: {{ stop_time: {stop_time}, seed: {seed}{boot} }}\n"
             f"network:\n  graph:\n    type: gml\n    inline: |\n"
             f"{_indent(gml, '      ')}\n"
             f"experimental:\n" + "\n".join(exp) + "\n"
@@ -410,10 +422,13 @@ def tgen_tier_yaml(n_hosts: int, n_servers: int | None = None,
                    experimental_extra: dict | None = None,
                    n_core: int = 4, n_mid: int = 8,
                    n_leaf: int = 40,
-                   tcp: dict | None = None) -> str:
+                   tcp: dict | None = None,
+                   client_final_state: str | None = None) -> str:
     """BASELINE config 3: tgen-style TCP transfers on the 3-tier graph.
     Servers live on mid-tier nodes; clients on leaves download
-    `count` x `nbytes` from a deterministic server choice."""
+    `count` x `nbytes` from a deterministic server choice.  Clients
+    are expected to exit 0 unless `client_final_state` says otherwise
+    (a window that stops mid-transfer)."""
     gml = three_tier_gml(n_core=n_core, n_mid=n_mid, n_leaf=n_leaf)
     if n_servers is None:
         n_servers = max(1, n_hosts // 50)
@@ -430,6 +445,8 @@ def tgen_tier_yaml(n_hosts: int, n_servers: int | None = None,
             f'      - {{ path: tgen-server, args: ["8080"], '
             f'expected_final_state: running }}')
     n_clients = n_hosts - n_servers
+    final = (f", expected_final_state: {client_final_state}"
+             if client_final_state else "")
     for i in range(n_clients):
         name = f"client{i:05d}"
         server = server_names[i % n_servers]
@@ -440,7 +457,7 @@ def tgen_tier_yaml(n_hosts: int, n_servers: int | None = None,
             f"{tl}    processes:\n"
             f'      - {{ path: tgen-client, '
             f'args: [{server}, "8080", "{nbytes}", "{count}"], '
-            f'start_time: {start_ms} ms }}')
+            f'start_time: {start_ms} ms{final} }}')
     return (f"general: {{ stop_time: {stop_time}, seed: {seed} }}\n"
             f"network:\n  graph:\n    type: gml\n    inline: |\n"
             f"{_indent(gml, '      ')}\n"

@@ -131,8 +131,6 @@ def main(argv=None) -> int:
     ev.add_argument("dataset")
     ev.add_argument("--holdout")
     args = ap.parse_args(argv)
-    from shadow_tpu.utils.platform import honor_platform_env
-    honor_platform_env()
     from shadow_tpu.sweep.dataset import DatasetError
     try:
         if args.cmd == "train":

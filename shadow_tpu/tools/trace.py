@@ -1168,8 +1168,6 @@ def main(argv=None) -> int:
             sub.add_argument("--top", type=int, default=10,
                              help="rows in the report (default 10)")
         sargs = sub.parse_args(argv[1:])
-        from shadow_tpu.utils.platform import honor_platform_env
-        honor_platform_env()
         if argv[0] == "net":
             return 0 if net_report(sargs.data_dir,
                                    top_n=sargs.top) else 1
@@ -1201,8 +1199,6 @@ def main(argv=None) -> int:
                     help="host count for --smoke (default 50)")
     args = ap.parse_args(argv)
 
-    from shadow_tpu.utils.platform import honor_platform_env
-    honor_platform_env()
 
     if args.smoke:
         return smoke(args.hosts)

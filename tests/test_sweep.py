@@ -115,6 +115,24 @@ def micro_campaign(tmp_path_factory):
     return dirs
 
 
+def test_runner_parent_never_imports_jax(tmp_path):
+    """A point's process may need the chip, which one process holds
+    at a time: the campaign parent must stay off JAX entirely."""
+    import subprocess
+    import sys
+    spec = dict(MICRO_SPEC, axes={"fan_in": [2]})
+    code = (
+        "import json, sys\n"
+        "from shadow_tpu.sweep import runner\n"
+        f"runner.run_campaign(json.loads({json.dumps(spec)!r}), "
+        f"{str(tmp_path)!r}, log=lambda m: None)\n"
+        "assert 'jax' not in sys.modules, 'runner parent imported jax'\n")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run([sys.executable, "-c", code], cwd=root,
+                          capture_output=True, text=True, timeout=240)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+
+
 def test_two_run_dataset_byte_identity(micro_campaign):
     da = ds_mod.aggregate(MICRO_SPEC, micro_campaign[0])
     db = ds_mod.aggregate(MICRO_SPEC, micro_campaign[1])
