@@ -32,6 +32,7 @@ from shadow_tpu.host.host import Host
 from shadow_tpu.host.process import Process
 from shadow_tpu.host.syscalls import SyscallHandler
 from shadow_tpu.net.dns import Dns
+from shadow_tpu.trace.recorder import Span
 
 
 @dataclass
@@ -358,6 +359,8 @@ class Manager:
         # must fail (or warn, under an all-quarantine fleet) before
         # the first spawn, naming the exact limit to raise.
         self.containment = None
+        # add_commit_observer: called at every commit boundary of run.
+        self._commit_observers: list = []
         if managed_hosts:
             from shadow_tpu.svc.containment import (ContainmentPlane,
                                                     preflight_managed)
@@ -833,6 +836,16 @@ class Manager:
 
             list(self._pool.map(run_worker, range(n)))
 
+    def add_commit_observer(self, fn) -> None:
+        """Call `fn(start_ns, rounds)` at every commit boundary of
+        `run`: the top of each round-loop iteration, after the
+        containment check, where every event before `start_ns` has
+        committed and `rounds` conservative rounds are done (a round,
+        a C++ span or a device span ends there), and once more when the
+        loop ends, with the run's end time.  `fn` may raise to end the
+        run; the exception propagates out of `run`."""
+        self._commit_observers.append(fn)
+
     def run(self) -> SimSummary:
         import sys
         stop = self.config.general.stop_time_ns
@@ -937,6 +950,7 @@ class Manager:
         # span-served rounds, the per-round tail for the rest), so the
         # attribution report always sums to summary.rounds.
         audit = self.audit
+        commit_obs = self._commit_observers
         flight = self.flight
         fr_sim = flight.sim if flight is not None else None
         fr_wall = flight.wall if flight is not None else None
@@ -1057,6 +1071,9 @@ class Manager:
                 # (docs/ROBUSTNESS.md).
                 for hid, _cause in self.containment.take_pending():
                     self._apply_quarantine(hid, start, fr_sim)
+            if commit_obs:
+                for fn in commit_obs:
+                    fn(start, summary.rounds)
             round_reason = per_round_static
             if span_ok:
                 if getattr(self.propagator, "_outbox", None):
@@ -1346,10 +1363,8 @@ class Manager:
             self.propagator.begin_round(start, window_end)
             if flight is not None:
                 pk0 = getattr(self.propagator, "packets_batched", 0)
-                t0 = fr_wall.now()
-                self._run_hosts(window_end)
-                t1 = fr_wall.now()
-                fr_wall.add("host-loop", t1 - t0, t0)
+                with Span(fr_wall, "host-loop"):
+                    self._run_hosts(window_end)
                 if self.sctrace is not None:
                     # Per-round managed-host phase wall: the slice of
                     # host-loop this round spent in the syscall seam
@@ -1358,9 +1373,8 @@ class Manager:
                     d = self.sctrace.round_phase_delta()
                     if d:
                         fr_wall.add("syscall-service", d)
-                inflight_min = self.propagator.finish_round()
-                t2 = fr_wall.now()
-                fr_wall.add("propagate", t2 - t1, t1)
+                with Span(fr_wall, "propagate"):
+                    inflight_min = self.propagator.finish_round()
                 if fr_sim is not None:
                     fr_sim.event(
                         window_end, trev.FR_ROUND, round_reason,
@@ -1416,6 +1430,8 @@ class Manager:
                     nxt = inflight_min
                 start = nxt
         summary.end_time_ns = min(start, stop) if start is not None else stop
+        for fn in commit_obs:
+            fn(summary.end_time_ns, summary.rounds)  # the last boundary
         if self.containment is not None:
             # The round loop is over: end-of-run forced teardown of
             # still-running binaries must not read as failures, and a

@@ -38,6 +38,8 @@ import numpy as np
 from shadow_tpu.core.rng import STREAM_PACKET_LOSS, mix_key, threefry2x32_jax
 from shadow_tpu.core.simtime import TIME_NEVER
 from shadow_tpu.ops.span_mesh import SpanMeshMixin
+from shadow_tpu.trace.events import KS_NAMES
+from shadow_tpu.trace.recorder import Span
 
 I64_MAX = np.int64(1 << 62)  # "no event" sentinel (== TIME_NEVER)
 
@@ -548,6 +550,11 @@ class PholdSpanRunner(SpanMeshMixin):
                                 ib_t < I64_MAX)
             st = ks_count(st, KS_POP, due)
             return ks_count(st, KS_TIMERS, due & ~pick_ib)
+
+        def stage(code):
+            """Name scope of one stage: its KS_NAMES string, so the
+            device trace and kernel-sim.bin name stages alike."""
+            return jax.named_scope(KS_NAMES[code])
 
         def th_push(st, mask, time, seq, kind, tgt):
             free = jnp.argmin(st["th_valid"], axis=1)
@@ -1388,16 +1395,20 @@ class PholdSpanRunner(SpanMeshMixin):
                 # XLA skips the whole vectorized stage body at runtime
                 # when no host sits in that continuation (the common
                 # case — chains concentrate activity in 2-3 stages per
-                # iteration).
-                def guard(st, mask, fn, code=None):
-                    st = ks_count(st, code, mask) \
-                        if code is not None else st
-                    return jax.lax.cond(mask.any(), fn,
-                                        lambda s, _m: s, st, mask)
+                # iteration).  Every stage runs under its KS_NAMES
+                # scope, so device-trace op names carry the
+                # kernel-sim.bin stage names.
+                def guard(st, mask, fn, code):
+                    with stage(code):
+                        st = ks_count(st, code, mask)
+                        return jax.lax.cond(mask.any(), fn,
+                                            lambda s, _m: s, st, mask)
 
-                st = ks_count_pop(st, st["cont"] == C_IDLE,
-                                  window_end)
-                st = op_pop_event(st, st["cont"] == C_IDLE, window_end)
+                with stage(KS_POP):
+                    st = ks_count_pop(st, st["cont"] == C_IDLE,
+                                      window_end)
+                    st = op_pop_event(st, st["cont"] == C_IDLE,
+                                      window_end)
                 st = guard(st, st["cont"] == C_M_STEP,
                            lambda s, m: op_step(s, m, False), KS_STEP)
                 st = guard(st, st["cont"] == C_S_STEP,
@@ -1422,23 +1433,30 @@ class PholdSpanRunner(SpanMeshMixin):
                 # op just moved waits for the next one) — matching the
                 # engine's one-op-at-a-time per host order.  Kept as
                 # the differential comparator for the fused path.
+                # ks_count touches only the ks_* counters, so each
+                # stage's count sits in its scope beside its op.
                 cont0 = st["cont"]
-                st = ks_count(st, KS_INET_OUT, cont0 == C_R1)
-                st = ks_count(st, KS_CODEL, cont0 == C_R2)
-                st = ks_count(st, KS_STEP, (cont0 == C_M_STEP)
-                              | (cont0 == C_S_STEP))
-                st = ks_count(st, KS_ARM, (cont0 == C_M_RECV)
-                              | (cont0 == C_S_POST))
-                st = op_relay(st, 1, cont0 == C_R1)
-                st = op_relay(st, 2, cont0 == C_R2)
-                st = op_step(st, cont0 == C_M_STEP, False)
-                st = op_step(st, cont0 == C_S_STEP, True)
-                st = op_stage2(st, (cont0 == C_M_RECV)
-                               | (cont0 == C_S_POST))
-                # Counted against the state op_pop_event will actually
-                # read (earlier ops may have armed timers).
-                st = ks_count_pop(st, cont0 == C_IDLE, window_end)
-                st = op_pop_event(st, cont0 == C_IDLE, window_end)
+                with stage(KS_INET_OUT):
+                    st = ks_count(st, KS_INET_OUT, cont0 == C_R1)
+                    st = op_relay(st, 1, cont0 == C_R1)
+                with stage(KS_CODEL):
+                    st = ks_count(st, KS_CODEL, cont0 == C_R2)
+                    st = op_relay(st, 2, cont0 == C_R2)
+                with stage(KS_STEP):
+                    st = ks_count(st, KS_STEP, (cont0 == C_M_STEP)
+                                  | (cont0 == C_S_STEP))
+                    st = op_step(st, cont0 == C_M_STEP, False)
+                    st = op_step(st, cont0 == C_S_STEP, True)
+                with stage(KS_ARM):
+                    arm = (cont0 == C_M_RECV) | (cont0 == C_S_POST)
+                    st = ks_count(st, KS_ARM, arm)
+                    st = op_stage2(st, arm)
+                with stage(KS_POP):
+                    # Counted against the state op_pop_event will
+                    # actually read (earlier ops may have armed
+                    # timers).
+                    st = ks_count_pop(st, cont0 == C_IDLE, window_end)
+                    st = op_pop_event(st, cont0 == C_IDLE, window_end)
             st = mark_abort(st, iters > (np.int64(1) << 22), AB_STRUCT)
             return st, window_end, iters + 1
 
@@ -1591,6 +1609,60 @@ class PholdSpanRunner(SpanMeshMixin):
             return ((rounds < max_rounds) & (start < limit)
                     & (start < stop) & (st["abort_code"] == 0))
 
+        def sample(st, start, window_end):
+            """Fabric observatory at the round boundary: same
+            grid-crossing rule as the engine's fab_sample_round and
+            the object path (trace/fabricstat.py)."""
+            do = (start // np.int64(fab_iv)
+                  != window_end // np.int64(fab_iv))
+            row = jnp.where(do, st["fab_n"], jnp.int32(FABR + 8))
+            depth = (st["cq_len"] - st["cq_pos"]).astype(jnp.int64)
+            flags = (jnp.where(depth > 0, FB_ACT_CODEL, 0)
+                     | jnp.where(st["r1_pending"] == 1,
+                                 FB_ACT_TB_OUT, 0)
+                     | jnp.where(st["r2_pending"] == 1,
+                                 FB_ACT_TB_IN, 0)
+                     | jnp.where(st["eth_psent"]
+                                 + st["eth_precv"] > 0,
+                                 FB_ACT_LINK, 0))
+            head = st["cq_enq"][hidx, st["cq_pos"] % C]
+            sojourn = jnp.where(depth > 0, window_end - head,
+                                jnp.int64(0))
+
+            def bucket_peek(r):
+                nr = st[f"r{r}_next"]
+                bal = st[f"r{r}_bal"]
+                k = 1 + (window_end - nr) // np.int64(REFILL_NS)
+                adv = jnp.minimum(st[f"r{r}_cap"],
+                                  bal + k * st[f"r{r}_refill"])
+                return jnp.where((nr == 0) | (window_end < nr),
+                                 bal, adv)
+
+            st = dict(st)
+            st["fab_t"] = st["fab_t"].at[row].set(
+                window_end, mode="drop")
+            st["fab_flags"] = st["fab_flags"].at[row].set(
+                flags.astype(jnp.int32), mode="drop")
+            for name, val in (
+                    ("qdepth", depth),
+                    ("qbytes", st["codel_bytes"]),
+                    ("sojourn", sojourn),
+                    ("qenq", st["codel_enq_pkts"]),
+                    ("qdrops", st["codel_dropped"]),
+                    ("qmarks", st["codel_marked"]),
+                    ("r1_bal", bucket_peek(1)),
+                    ("r1_stalls", st["r1_stalls"]),
+                    ("r2_bal", bucket_peek(2)),
+                    ("r2_stalls", st["r2_stalls"]),
+                    ("psent", st["eth_psent"]),
+                    ("bsent", st["eth_bsent"]),
+                    ("precv", st["eth_precv"]),
+                    ("brecv", st["eth_brecv"])):
+                st[f"fab_{name}"] = st[f"fab_{name}"].at[
+                    row].set(val.astype(jnp.int64), mode="drop")
+            st["fab_n"] = st["fab_n"] + do.astype(jnp.int32)
+            return st
+
         def round_body(carry):
             (st, start, runahead, rounds, busy_rounds, packets,
              busy_end, stop, limit, max_rounds, iters) = carry
@@ -1598,61 +1670,11 @@ class PholdSpanRunner(SpanMeshMixin):
             st, _we, it = jax.lax.while_loop(
                 micro_cond, micro_iter,
                 (st, window_end, jnp.int64(0)))
-            st, n_out, min_lat = propagate(st, window_end)
+            with jax.named_scope("propagate"):
+                st, n_out, min_lat = propagate(st, window_end)
             if fabric:
-                # Fabric observatory at the round boundary: same
-                # grid-crossing rule as the engine's fab_sample_round
-                # and the object path (trace/fabricstat.py).
-                do = (start // np.int64(fab_iv)
-                      != window_end // np.int64(fab_iv))
-                row = jnp.where(do, st["fab_n"],
-                                jnp.int32(FABR + 8))
-                depth = (st["cq_len"] - st["cq_pos"]).astype(
-                    jnp.int64)
-                flags = (jnp.where(depth > 0, FB_ACT_CODEL, 0)
-                         | jnp.where(st["r1_pending"] == 1,
-                                     FB_ACT_TB_OUT, 0)
-                         | jnp.where(st["r2_pending"] == 1,
-                                     FB_ACT_TB_IN, 0)
-                         | jnp.where(st["eth_psent"]
-                                     + st["eth_precv"] > 0,
-                                     FB_ACT_LINK, 0))
-                head = st["cq_enq"][hidx, st["cq_pos"] % C]
-                sojourn = jnp.where(depth > 0, window_end - head,
-                                    jnp.int64(0))
-
-                def bucket_peek(r):
-                    nr = st[f"r{r}_next"]
-                    bal = st[f"r{r}_bal"]
-                    k = 1 + (window_end - nr) // np.int64(REFILL_NS)
-                    adv = jnp.minimum(st[f"r{r}_cap"],
-                                      bal + k * st[f"r{r}_refill"])
-                    return jnp.where((nr == 0) | (window_end < nr),
-                                     bal, adv)
-
-                st = dict(st)
-                st["fab_t"] = st["fab_t"].at[row].set(
-                    window_end, mode="drop")
-                st["fab_flags"] = st["fab_flags"].at[row].set(
-                    flags.astype(jnp.int32), mode="drop")
-                for name, val in (
-                        ("qdepth", depth),
-                        ("qbytes", st["codel_bytes"]),
-                        ("sojourn", sojourn),
-                        ("qenq", st["codel_enq_pkts"]),
-                        ("qdrops", st["codel_dropped"]),
-                        ("qmarks", st["codel_marked"]),
-                        ("r1_bal", bucket_peek(1)),
-                        ("r1_stalls", st["r1_stalls"]),
-                        ("r2_bal", bucket_peek(2)),
-                        ("r2_stalls", st["r2_stalls"]),
-                        ("psent", st["eth_psent"]),
-                        ("bsent", st["eth_bsent"]),
-                        ("precv", st["eth_precv"]),
-                        ("brecv", st["eth_brecv"])):
-                    st[f"fab_{name}"] = st[f"fab_{name}"].at[
-                        row].set(val.astype(jnp.int64), mode="drop")
-                st["fab_n"] = st["fab_n"] + do.astype(jnp.int32)
+                with jax.named_scope("sample"):
+                    st = sample(st, start, window_end)
             runahead = jnp.where(
                 (min_lat > 0) & (min_lat < runahead), min_lat,
                 runahead)
@@ -1760,32 +1782,27 @@ class PholdSpanRunner(SpanMeshMixin):
         """Fresh engine export -> state dict, or the int/None
         eligibility verdict passed through from span_export_phold."""
         w = self.wall
-        t0 = w.now() if w is not None else 0
-        d = self.engine.span_export_phold(
-            self.CAP_I, self.CAP_T, self.CAP_R, self.CAP_S,
-            self.CAP_C, self.CAP_P)
-        if w is not None:
-            t1 = w.now()
-            w.add("export", t1 - t0, t0)
+        with Span(w, "export"):
+            d = self.engine.span_export_phold(
+                self.CAP_I, self.CAP_T, self.CAP_R, self.CAP_S,
+                self.CAP_C, self.CAP_P)
         if d is None or isinstance(d, int):
             return d
-        # Codec byte volume, engine -> host (dispatch attribution).
-        self.export_bytes += sum(
-            len(v) for v in d.values()
-            if isinstance(v, (bytes, bytearray, memoryview)))
-        st = self._to_arrays(d)  # also sets self.family/_pay
-        # Cache the static config as committed device arrays: the
-        # host->device transfer of the largest columns (peers is
-        # H x P) is paid once per export, and every later dispatch —
-        # fresh or resident — reuses the device copies (device_put
-        # on an already-placed array is a no-op).
-        import jax
-        self._static_cols = {
-            k: self._put_static(jax, st[k]) for k in RESIDENT_STATIC}
-        st.update(self._static_cols)
-        if w is not None:
-            t2 = w.now()
-            w.add("convert", t2 - t1, t1)
+        with Span(w, "convert"):
+            # Codec byte volume, engine -> host (dispatch attribution).
+            self.export_bytes += sum(
+                len(v) for v in d.values()
+                if isinstance(v, (bytes, bytearray, memoryview)))
+            st = self._to_arrays(d)  # also sets self.family/_pay
+            # Cache the static config as committed device arrays: the
+            # host->device transfer of the largest columns (peers is
+            # H x P) is paid once per export, and every later dispatch
+            # — fresh or resident — reuses the device copies
+            # (device_put on an already-placed array is a no-op).
+            import jax
+            self._static_cols = {
+                k: self._put_static(jax, st[k]) for k in RESIDENT_STATIC}
+            st.update(self._static_cols)
         return st
 
     def _resident_input(self):
@@ -1877,50 +1894,53 @@ class PholdSpanRunner(SpanMeshMixin):
                 self._static_cols["peers"].shape[1])
             if self.mesh is not None:
                 st = self._mesh_put(st)
+        import jax
         w = self.wall
         for _grow in range(4):
-            t0 = time.perf_counter_ns()  # shadow-lint: allow[wall-clock] dispatch attribution (metrics.wall)
             spec_rec, landed = landed, None
             if spec_rec is not None:
-                fresh_fn = False
                 out = spec_rec["out"]
+                # A landed window's wait is host idle (the device is
+                # still running it); from its return the device idles
+                # until the next window's dispatch returns.
+                with Span(w, "land-wait") as leg:
+                    jax.block_until_ready(out)
+                self.overlap_wait_ns += leg.ns
+                t_ready = leg.t1
             else:
+                # The first dispatch THROUGH A GIVEN BUILT FN pays
+                # trace+XLA compile (capacity regrows rebuild the fn
+                # and recompile): credit those separately so
+                # "execute" stays the steady state.  The same split
+                # feeds the explicit fn_cache accounting
+                # (metrics.wall.dispatch.fn_cache).
                 fresh_fn = id(self._fn) not in self._timed_fns
-                out = self._span_call(
-                    self._fn,
-                    st, self._lat, self._thr, self._node,
-                    self._ips_sorted, self._ips_perm,
-                    np.uint32(self._k[0]), np.uint32(self._k[1]),
-                    np.int64(self.bootstrap_end), np.int64(self._pay),
-                    start, stop, limit, runahead, mr)
+                with Span(w, "compile" if fresh_fn else "execute") as leg:
+                    out = self._span_call(
+                        self._fn,
+                        st, self._lat, self._thr, self._node,
+                        self._ips_sorted, self._ips_perm,
+                        np.uint32(self._k[0]), np.uint32(self._k[1]),
+                        np.int64(self.bootstrap_end),
+                        np.int64(self._pay),
+                        start, stop, limit, runahead, mr)
+                    jax.block_until_ready(out)
+                if fresh_fn:
+                    self._credit_build(self._fn, leg.ns)
+                t_ready = None
             (st_out, next_start, ra, rounds, busy_rounds, packets,
              busy_end, span_iters) = out
             self.state_devices = len(st_out["now"].sharding.device_set)
-            st_np = {k: np.asarray(v) for k, v in st_out.items()}
+            with Span(w, "fetch") as fetch:
+                st_np = {k: np.asarray(v) for k, v in st_out.items()}
             code = int(st_np["abort_code"])
-            # The first dispatch THROUGH A GIVEN BUILT FN pays
-            # trace+XLA compile (capacity regrows rebuild the fn and
-            # recompile): credit those separately so "execute" stays
-            # the steady state (the np.asarray forced device
-            # completion).  The same split feeds the explicit
-            # fn_cache accounting (metrics.wall.dispatch.fn_cache).
-            dt = time.perf_counter_ns() - t0  # shadow-lint: allow[wall-clock] dispatch attribution (metrics.wall)
+            dt = leg.ns + fetch.ns
             self._timed_fns.add(id(self._fn))
             self.device_wall_ns += dt
             if spec_rec is not None:
-                # A landed window's force wait is host idle (the
-                # device was already running); its dispatch->force
-                # wall is the pipe the idle fractions divide by.
-                self.overlap_wait_ns += dt
-                self.overlap_pipe_ns += \
-                    time.perf_counter_ns() - spec_rec["t_disp"]  # shadow-lint: allow[wall-clock] dispatch attribution (metrics.wall)
-                if w is not None:
-                    w.add("overlap-land", dt, t0)
-            else:
-                if fresh_fn:
-                    self._credit_build(self._fn, dt)
-                if w is not None:
-                    w.add("compile" if fresh_fn else "execute", dt, t0)
+                # dispatch -> fetched: the pipe the idle fractions
+                # divide by.
+                self.overlap_pipe_ns += fetch.t1 - spec_rec["t_disp"]
             if code == 0:
                 break
             # Speculative-window waste: the aborted dispatch's wall
@@ -2002,7 +2022,7 @@ class PholdSpanRunner(SpanMeshMixin):
                 and int(next_start) < int(limit):
             spec = self._speculate(st_out, int(next_start), int(stop),
                                    int(limit), ra_out, dynamic,
-                                   spec_mr)
+                                   spec_mr, t_ready)
         traces = None
         if self.tracing:
             n = int(st_np["tr_n"])
@@ -2028,32 +2048,32 @@ class PholdSpanRunner(SpanMeshMixin):
                 "owner": st_np["tr_owner"][:n].astype(
                     np.int32).tobytes(),
             }
-        t0 = w.now() if w is not None else 0
-        # fab_*/ks_* sample buffers are span-local output, not engine
-        # state.
-        back = self._from_arrays(
-            {k: v for k, v in st_np.items()
-             if not k.startswith("fab_")
-             and not k.startswith("ks_")})
-        # Codec byte volume, host -> engine (dispatch attribution).
-        self.import_bytes += sum(
-            len(v) for v in back.values()
-            if isinstance(v, (bytes, bytearray, memoryview)))
-        self.engine.span_import_phold(
-            back, self.CAP_I, self.CAP_T, self.CAP_R, self.CAP_S,
-            self.CAP_C, self.CAP_P, traces)
-        if self.fabric is not None:
-            from shadow_tpu.trace.fabricstat import emit_device_rows
-            emit_device_rows(self.fabric, st_np, self._H)
-        if self.kern is not None:
-            # One KS_REC per committed span (aborted spans rolled
-            # back above and recorded nothing — the conservation law).
-            from shadow_tpu.trace.events import FAM_PHOLD
-            self.kern.record_span(
-                int(start), FAM_PHOLD, self._H, int(rounds),
-                int(span_iters), st_np["ks_fires"], st_np["ks_lanes"])
-        if w is not None:
-            w.add("import", w.now() - t0, t0)
+        with Span(w, "import"):
+            # fab_*/ks_* sample buffers are span-local output, not
+            # engine state.
+            back = self._from_arrays(
+                {k: v for k, v in st_np.items()
+                 if not k.startswith("fab_")
+                 and not k.startswith("ks_")})
+            # Codec byte volume, host -> engine (dispatch attribution).
+            self.import_bytes += sum(
+                len(v) for v in back.values()
+                if isinstance(v, (bytes, bytearray, memoryview)))
+            self.engine.span_import_phold(
+                back, self.CAP_I, self.CAP_T, self.CAP_R, self.CAP_S,
+                self.CAP_C, self.CAP_P, traces)
+            if self.fabric is not None:
+                from shadow_tpu.trace.fabricstat import emit_device_rows
+                emit_device_rows(self.fabric, st_np, self._H)
+            if self.kern is not None:
+                # One KS_REC per committed span (aborted spans rolled
+                # back above and recorded nothing — the conservation
+                # law).
+                from shadow_tpu.trace.events import FAM_PHOLD
+                self.kern.record_span(
+                    int(start), FAM_PHOLD, self._H, int(rounds),
+                    int(span_iters), st_np["ks_fires"],
+                    st_np["ks_lanes"])
         # The import itself bumps the epoch; record it AFTER, so the
         # resident copy is valid exactly until anything else touches
         # the engine.
@@ -2070,7 +2090,7 @@ class PholdSpanRunner(SpanMeshMixin):
                 int(next_start), int(busy_end), ra_out)
 
     def _speculate(self, st_out, start, stop, limit, runahead,
-                   dynamic, spec_mr):
+                   dynamic, spec_mr, t_ready=None):
         """Async double-buffered dispatch of window K+1 (ISSUE 16):
         rebuild the span input from the just-committed device output
         (the residency law — _resident_input — so no export touches
@@ -2078,27 +2098,27 @@ class PholdSpanRunner(SpanMeshMixin):
         returns unforced device arrays and XLA executes them on its
         own threads while the caller runs the host-side import.  The
         returned record is a Future in all but name; SpanMeshMixin
-        owns its commit/land/refuse protocol."""
+        owns its commit/land/refuse protocol.  `t_ready` (window K
+        landed and ready, perf_counter ns) opens the pipeline
+        bubble this dispatch closes."""
         mr = self._clamp_mr(spec_mr)
-        saved = self._res_st
-        self._res_st = st_out
-        st = self._resident_input()
-        self._res_st = saved
-        if self.mesh is not None:
-            st = self._mesh_put(st)
-        w = self.wall
-        t0 = time.perf_counter_ns()  # shadow-lint: allow[wall-clock] dispatch attribution (metrics.wall)
-        out = self._span_call(
-            self._fn,
-            st, self._lat, self._thr, self._node,
-            self._ips_sorted, self._ips_perm,
-            np.uint32(self._k[0]), np.uint32(self._k[1]),
-            np.int64(self.bootstrap_end), np.int64(self._pay),
-            start, stop, limit, runahead, mr)
+        with Span(self.wall, "dispatch") as disp:
+            saved = self._res_st
+            self._res_st = st_out
+            st = self._resident_input()
+            self._res_st = saved
+            if self.mesh is not None:
+                st = self._mesh_put(st)
+            out = self._span_call(
+                self._fn,
+                st, self._lat, self._thr, self._node,
+                self._ips_sorted, self._ips_perm,
+                np.uint32(self._k[0]), np.uint32(self._k[1]),
+                np.int64(self.bootstrap_end), np.int64(self._pay),
+                start, stop, limit, runahead, mr)
         self.overlap_windows += 1
-        if w is not None:
-            w.add("dispatch",
-                  time.perf_counter_ns() - t0, t0)  # shadow-lint: allow[wall-clock] dispatch attribution (metrics.wall)
+        if t_ready is not None:
+            self._book_idle(disp.t1 - t_ready)
         return self._speculate_record(
-            out, t0, (start, stop, limit, runahead, bool(dynamic),
-                      mr))
+            out, disp.t0, (start, stop, limit, runahead, bool(dynamic),
+                           mr))

@@ -139,12 +139,20 @@ class SpanMeshMixin:
     overlap_hits = 0         # ...landed and consumed
     overlap_refusals = 0     # ...refused (params/epoch mismatch)
     overlap_stale = 0        # refusals caused by state_epoch drift
-    overlap_wait_ns = 0      # HOST idle: wall blocked forcing a
-    #                          landed window (device still running)
-    overlap_idle_ns = 0      # DEVICE idle (lower bound): flush->land
-    #                          gap, counted only when the window was
-    #                          already ready at flush time
-    overlap_pipe_ns = 0      # dispatch->force wall of landed windows
+    overlap_wait_ns = 0      # HOST idle: the `land-wait` legs (block
+    #                          on a landed window, device still running)
+    overlap_idle_ns = 0      # DEVICE idle the host causes, a lower
+    #                          bound (the `pipeline-bubble` wall phase):
+    #                          per landed window, K ready -> K+1's async
+    #                          dispatch returned (`fetch` + `dispatch`),
+    #                          plus the flush->land gap when K was
+    #                          already ready at flush.  Uncounted: a K
+    #                          that finishes before its flush idles from
+    #                          its finish to the flush; one that finishes
+    #                          after its flush idles from its finish to
+    #                          its landing (the host cannot see either
+    #                          finish without polling)
+    overlap_pipe_ns = 0      # dispatch -> fetched wall of landed windows
 
     def _speculate_record(self, out, t_disp, params):
         """The Future-shaped in-flight record: unforced device arrays
@@ -162,7 +170,7 @@ class SpanMeshMixin:
         LATER engine mutation invalidates the record at landing) and
         probe — without blocking — whether the device already
         finished, so the flush->land gap can be attributed as device
-        idle honestly (ready_at_flush False keeps it a lower bound)."""
+        idle (ready_at_flush False leaves it uncounted: a lower bound)."""
         spec["epoch"] = self.engine.state_epoch()
         spec["ready_at_flush"] = spec["out"][0]["abort_code"].is_ready()
         spec["t_flush"] = time.perf_counter_ns()  # shadow-lint: allow[wall-clock] dispatch attribution (metrics.wall)
@@ -192,8 +200,16 @@ class SpanMeshMixin:
         self.resident_hits += 1
         if spec["ready_at_flush"]:
             now = time.perf_counter_ns()  # shadow-lint: allow[wall-clock] dispatch attribution (metrics.wall)
-            self.overlap_idle_ns += now - spec["t_flush"]
+            self._book_idle(now - spec["t_flush"])
         return spec
+
+    def _book_idle(self, ns: int) -> None:
+        """Device idle the host caused (a lower bound, see
+        `overlap_idle_ns`): `overlap_idle_ns` and the `pipeline-bubble`
+        wall aggregate (no event, so it labels no gap)."""
+        self.overlap_idle_ns += ns
+        if self.wall is not None:
+            self.wall.add("pipeline-bubble", ns)
 
     def overlap_summary(self) -> dict:
         """The per-family `overlap` block in metrics.wall.dispatch."""

@@ -32,6 +32,8 @@ import numpy as np
 from shadow_tpu.core.rng import STREAM_PACKET_LOSS, mix_key, threefry2x32_jax
 from shadow_tpu.core.simtime import TIME_NEVER
 from shadow_tpu.ops.span_mesh import SpanMeshMixin
+from shadow_tpu.trace.events import KS_NAMES
+from shadow_tpu.trace.recorder import Span
 
 I64_MAX = np.int64(1 << 62)
 SEQ_HALF = np.int64(1 << 31)
@@ -720,6 +722,11 @@ class TcpSpanRunner(SpanMeshMixin):
             ib_t, th_t = next_event_time(st)
             due = mask & (jnp.minimum(ib_t, th_t) < window_end)
             return ks_count(st, KS_POP, due)
+
+        def stage(code):
+            """Name scope of one stage (phold_span twin): its KS_NAMES
+            string, so the device trace and kernel-sim.bin agree."""
+            return jax.named_scope(KS_NAMES[code])
 
         def draw_seq(st, mask):
             v = st["event_seq"]
@@ -2067,15 +2074,18 @@ class TcpSpanRunner(SpanMeshMixin):
                 # Host.trace_lines).  Each stage is guarded by an
                 # any-lane-active cond so XLA skips the vectorized
                 # body of stages nobody occupies this iteration.
-                def guard(st, mask, fn, code=None):
-                    st = ks_count(st, code, mask) \
-                        if code is not None else st
-                    return jax.lax.cond(mask.any(), fn,
-                                        lambda s, _m: s, st, mask)
+                # Every stage runs under its KS_NAMES scope.
+                def guard(st, mask, fn, code):
+                    with stage(code):
+                        st = ks_count(st, code, mask)
+                        return jax.lax.cond(mask.any(), fn,
+                                            lambda s, _m: s, st, mask)
 
-                st = ks_count_pop(st, st["cont"] == C_IDLE,
-                                  window_end)
-                st = op_pop_event(st, st["cont"] == C_IDLE, window_end)
+                with stage(KS_POP):
+                    st = ks_count_pop(st, st["cont"] == C_IDLE,
+                                      window_end)
+                    st = op_pop_event(st, st["cont"] == C_IDLE,
+                                      window_end)
                 st = guard(st, st["cont"] == C_TMR, op_tmr, KS_TIMERS)
                 st = guard(st, st["cont"] == C_APP, op_app, KS_STEP)
                 st = guard(st, st["cont"] == C_R2, op_relay2,
@@ -2098,30 +2108,27 @@ class TcpSpanRunner(SpanMeshMixin):
                 # Reference (unfused) schedule: snapshot — one
                 # micro-op per host per iteration.  Kept as the
                 # differential comparator for the fused path.
+                # ks_count touches only the ks_* counters, so each
+                # stage's count sits in its scope beside its op.
                 cont0 = st["cont"]
-                st = ks_count(st, KS_INET_OUT, cont0 == C_R1)
-                st = ks_count(st, KS_CODEL, cont0 == C_R2)
-                st = ks_count(st, KS_ON_PACKET, cont0 == C_TCPIN)
-                st = ks_count(st, KS_REASM, cont0 == C_DRAIN)
-                st = ks_count(st, KS_ACK, cont0 == C_ACKDATA)
-                st = ks_count(st, KS_PUSH, cont0 == C_PUSH)
-                st = ks_count(st, KS_FLUSH, cont0 == C_FLUSH)
-                st = ks_count(st, KS_ARM, cont0 == C_ARM)
-                st = ks_count(st, KS_STEP, cont0 == C_APP)
-                st = ks_count(st, KS_TIMERS, cont0 == C_TMR)
-                st = op_relay1(st, cont0 == C_R1)
-                st = op_relay2(st, cont0 == C_R2)
-                st = op_tcpin(st, cont0 == C_TCPIN)
-                st = op_drain(st, cont0 == C_DRAIN)
-                st = op_ackdata(st, cont0 == C_ACKDATA)
-                st = op_push(st, cont0 == C_PUSH)
-                st = op_flush(st, cont0 == C_FLUSH)
-                st = op_arm(st, cont0 == C_ARM)
-                st = op_app(st, cont0 == C_APP)
-                st = op_tmr(st, cont0 == C_TMR)
-                # Counted against the state op_pop_event will read.
-                st = ks_count_pop(st, cont0 == C_IDLE, window_end)
-                st = op_pop_event(st, cont0 == C_IDLE, window_end)
+                for code, c, op in (
+                        (KS_INET_OUT, C_R1, op_relay1),
+                        (KS_CODEL, C_R2, op_relay2),
+                        (KS_ON_PACKET, C_TCPIN, op_tcpin),
+                        (KS_REASM, C_DRAIN, op_drain),
+                        (KS_ACK, C_ACKDATA, op_ackdata),
+                        (KS_PUSH, C_PUSH, op_push),
+                        (KS_FLUSH, C_FLUSH, op_flush),
+                        (KS_ARM, C_ARM, op_arm),
+                        (KS_STEP, C_APP, op_app),
+                        (KS_TIMERS, C_TMR, op_tmr)):
+                    with stage(code):
+                        st = ks_count(st, code, cont0 == c)
+                        st = op(st, cont0 == c)
+                with stage(KS_POP):
+                    # Counted against the state op_pop_event will read.
+                    st = ks_count_pop(st, cont0 == C_IDLE, window_end)
+                    st = op_pop_event(st, cont0 == C_IDLE, window_end)
             # Per-round runaway valve: a legitimate hot round is a few
             # thousand micro-iterations; a continuation-cycle bug must
             # abort in minutes, not hours (each iteration is a full
@@ -2277,14 +2284,9 @@ class TcpSpanRunner(SpanMeshMixin):
             return ((rounds < max_rounds) & (start < limit)
                     & (start < stop) & (st["abort_code"] == 0))
 
-        def round_body(carry):
-            (st, start, runahead, rounds, busy_rounds, packets,
-             busy_end, stop, limit, max_rounds, iters) = carry
-            window_end = jnp.minimum(start + runahead, stop)
-            st, _we, it = jax.lax.while_loop(
-                micro_cond, micro_iter,
-                (st, window_end, jnp.int64(0)))
-            st, n_out, min_lat = propagate(st, window_end)
+        def sample(st, start, window_end):
+            """Round-end sampling: sim-netstat and the fabric
+            observatory."""
             if netstat:
                 # Sim-netstat sample at the round boundary: the same
                 # stateless grid-crossing rule as the engine's
@@ -2355,6 +2357,20 @@ class TcpSpanRunner(SpanMeshMixin):
                     st[f"fab_{name}"] = st[f"fab_{name}"].at[
                         row].set(val.astype(jnp.int64), mode="drop")
                 st["fab_n"] = st["fab_n"] + do.astype(jnp.int32)
+            return st
+
+        def round_body(carry):
+            (st, start, runahead, rounds, busy_rounds, packets,
+             busy_end, stop, limit, max_rounds, iters) = carry
+            window_end = jnp.minimum(start + runahead, stop)
+            st, _we, it = jax.lax.while_loop(
+                micro_cond, micro_iter,
+                (st, window_end, jnp.int64(0)))
+            with jax.named_scope("propagate"):
+                st, n_out, min_lat = propagate(st, window_end)
+            if netstat or fabric:
+                with jax.named_scope("sample"):
+                    st = sample(st, start, window_end)
             runahead = jnp.where(
                 (min_lat > 0) & (min_lat < runahead), min_lat,
                 runahead)
@@ -2473,13 +2489,16 @@ class TcpSpanRunner(SpanMeshMixin):
         """Fresh engine export -> state dict, or the int/None
         eligibility verdict passed through from span_export_tcp."""
         w = self.wall
-        t0 = w.now() if w is not None else 0
-        d = self.engine.span_export_tcp(*self._caps())
-        if w is not None:
-            t1 = w.now()
-            w.add("export", t1 - t0, t0)
+        with Span(w, "export"):
+            d = self.engine.span_export_tcp(*self._caps())
         if d is None or isinstance(d, int):
             return d
+        with Span(w, "convert"):
+            st = self._convert(d)
+        return st
+
+    def _convert(self, d):
+        """Export dict -> span input state (the `convert` phase)."""
         # Codec byte volume, engine -> host (dispatch attribution).
         self.export_bytes += sum(
             len(v) for v in d.values()
@@ -2505,9 +2524,6 @@ class TcpSpanRunner(SpanMeshMixin):
             k: self._put_static(jax, st[k]) for k in RESIDENT_STATIC}
         st.update(self._static_cols)
         self._static_cols["_n_conns"] = st["_n_conns"]
-        if w is not None:
-            t2 = w.now()
-            w.add("convert", t2 - t1, t1)
         return st
 
     def _resident_input(self):
@@ -2659,49 +2675,50 @@ class TcpSpanRunner(SpanMeshMixin):
             self._fn = self._cached_build()
             if self.mesh is not None:
                 st = self._mesh_put(st)
+        import jax
         w = self.wall
         for _grow in range(4):
-            _tw = _time.perf_counter_ns()  # shadow-lint: allow[wall-clock] dispatch attribution (metrics.wall)
             spec_rec, landed = landed, None
             if spec_rec is not None:
-                fresh_fn = False
                 out = spec_rec["out"]
+                # phold_span twin: the landed window's wait is host
+                # idle; from its return the device idles until the
+                # next window's dispatch returns.
+                with Span(w, "land-wait") as leg:
+                    jax.block_until_ready(out)
+                self.overlap_wait_ns += leg.ns
+                t_ready = leg.t1
             else:
+                # First dispatch through a given built fn pays
+                # trace+XLA compile (capacity regrows rebuild it); the
+                # split feeds the explicit fn_cache accounting
+                # (metrics.wall.dispatch.fn_cache).
                 fresh_fn = id(self._fn) not in self._timed_fns
-                out = self._span_call(
-                    self._fn,
-                    st, self._lat, self._thr, self._node,
-                    self._ips_sorted, self._ips_perm,
-                    np.uint32(self._k[0]), np.uint32(self._k[1]),
-                    np.int64(self.bootstrap_end),
-                    start, stop, limit, runahead, mr)
+                with Span(w, "compile" if fresh_fn else "execute") as leg:
+                    out = self._span_call(
+                        self._fn,
+                        st, self._lat, self._thr, self._node,
+                        self._ips_sorted, self._ips_perm,
+                        np.uint32(self._k[0]), np.uint32(self._k[1]),
+                        np.int64(self.bootstrap_end),
+                        start, stop, limit, runahead, mr)
+                    jax.block_until_ready(out)
+                if fresh_fn:
+                    self._credit_build(self._fn, leg.ns)
+                t_ready = None
             (st_out, next_start, ra, rounds, busy_rounds, packets,
              busy_end, span_iters) = out
             self.state_devices = len(st_out["now"].sharding.device_set)
-            st_np = {k: np.asarray(v) for k, v in st_out.items()}
+            with Span(w, "fetch") as fetch:
+                st_np = {k: np.asarray(v) for k, v in st_out.items()}
             code = int(st_np["abort_code"])
-            # First dispatch through a given built fn pays trace+XLA
-            # compile (capacity regrows rebuild it); the split feeds
-            # the explicit fn_cache accounting
-            # (metrics.wall.dispatch.fn_cache).
-            _dt = _time.perf_counter_ns() - _tw  # shadow-lint: allow[wall-clock] dispatch attribution (metrics.wall)
+            _dt = leg.ns + fetch.ns
             self._timed_fns.add(id(self._fn))
             self.device_wall_ns += _dt
             if spec_rec is not None:
-                # A landed window's force wait is host idle (the
-                # device was already running); its dispatch->force
-                # wall is the pipe the idle fractions divide by.
-                self.overlap_wait_ns += _dt
-                self.overlap_pipe_ns += \
-                    _time.perf_counter_ns() - spec_rec["t_disp"]  # shadow-lint: allow[wall-clock] dispatch attribution (metrics.wall)
-                if w is not None:
-                    w.add("overlap-land", _dt, _tw)
-            else:
-                if fresh_fn:
-                    self._credit_build(self._fn, _dt)
-                if w is not None:
-                    w.add("compile" if fresh_fn else "execute",
-                          _dt, _tw)
+                # dispatch -> fetched: the pipe the idle fractions
+                # divide by.
+                self.overlap_pipe_ns += fetch.t1 - spec_rec["t_disp"]
             if code != 0:
                 # Speculative-window waste: an aborted dispatch's
                 # wall and its stepped rounds roll back unused.
@@ -2790,7 +2807,7 @@ class TcpSpanRunner(SpanMeshMixin):
                 and int(next_start) < int(limit):
             spec = self._speculate(st_out, int(next_start), int(stop),
                                    int(limit), ra_out, dynamic,
-                                   spec_mr)
+                                   spec_mr, t_ready)
         traces = None
         if self.tracing:
             n = int(st_np["tr_n"])
@@ -2818,30 +2835,29 @@ class TcpSpanRunner(SpanMeshMixin):
                     np.int32).tobytes(),
             }
         st_np["_n_conns"] = n_conns
-        _tw = w.now() if w is not None else 0
-        # tel_*/fab_*/ks_* sample buffers are span-local output, not
-        # engine state.
-        back = self._from_arrays(
-            {k: v for k, v in st_np.items()
-             if not k.startswith("tel_")
-             and not k.startswith("fab_")
-             and not k.startswith("ks_")})
-        # Codec byte volume, host -> engine (dispatch attribution).
-        self.import_bytes += sum(
-            len(v) for v in back.values()
-            if isinstance(v, (bytes, bytearray, memoryview)))
-        self.engine.span_import_tcp(back, *self._caps(), traces)
-        self._emit_netstat(st_np)
-        self._emit_fabric(st_np)
-        if self.kern is not None:
-            # One KS_REC per committed span (aborted spans rolled
-            # back and recorded nothing — the conservation law).
-            from shadow_tpu.trace.events import FAM_TCP
-            self.kern.record_span(
-                int(start), FAM_TCP, self._H, int(rounds),
-                int(span_iters), st_np["ks_fires"], st_np["ks_lanes"])
-        if w is not None:
-            w.add("import", w.now() - _tw, _tw)
+        with Span(w, "import"):
+            # tel_*/fab_*/ks_* sample buffers are span-local output,
+            # not engine state.
+            back = self._from_arrays(
+                {k: v for k, v in st_np.items()
+                 if not k.startswith("tel_")
+                 and not k.startswith("fab_")
+                 and not k.startswith("ks_")})
+            # Codec byte volume, host -> engine (dispatch attribution).
+            self.import_bytes += sum(
+                len(v) for v in back.values()
+                if isinstance(v, (bytes, bytearray, memoryview)))
+            self.engine.span_import_tcp(back, *self._caps(), traces)
+            self._emit_netstat(st_np)
+            self._emit_fabric(st_np)
+            if self.kern is not None:
+                # One KS_REC per committed span (aborted spans rolled
+                # back and recorded nothing — the conservation law).
+                from shadow_tpu.trace.events import FAM_TCP
+                self.kern.record_span(
+                    int(start), FAM_TCP, self._H, int(rounds),
+                    int(span_iters), st_np["ks_fires"],
+                    st_np["ks_lanes"])
         # Record AFTER the import's own epoch bump: the resident copy
         # is valid exactly until anything else touches the engine.
         self._res_st = st_out
@@ -2857,36 +2873,34 @@ class TcpSpanRunner(SpanMeshMixin):
                 int(next_start), int(busy_end), ra_out)
 
     def _speculate(self, st_out, start, stop, limit, runahead,
-                   dynamic, spec_mr):
+                   dynamic, spec_mr, t_ready=None):
         """Async double-buffered dispatch of window K+1 (phold_span
         twin): rebuild the span input from the just-committed device
         output via the residency law and dispatch WITHOUT forcing —
         XLA executes on its own threads while the caller runs the
         host-side import.  SpanMeshMixin owns the record's
-        commit/land/refuse protocol."""
-        import time as _time
+        commit/land/refuse protocol.  `t_ready` (window K landed and
+        ready) opens the pipeline bubble this dispatch closes."""
         mr = self._clamp_mr(spec_mr)
-        saved = self._res_st
-        self._res_st = st_out
-        st = self._resident_input()
-        self._res_st = saved
-        st = dict(st)
-        st.pop("_n_conns", None)
-        if self.mesh is not None:
-            st = self._mesh_put(st)
-        w = self.wall
-        t0 = _time.perf_counter_ns()  # shadow-lint: allow[wall-clock] dispatch attribution (metrics.wall)
-        out = self._span_call(
-            self._fn,
-            st, self._lat, self._thr, self._node,
-            self._ips_sorted, self._ips_perm,
-            np.uint32(self._k[0]), np.uint32(self._k[1]),
-            np.int64(self.bootstrap_end),
-            start, stop, limit, runahead, mr)
+        with Span(self.wall, "dispatch") as disp:
+            saved = self._res_st
+            self._res_st = st_out
+            st = self._resident_input()
+            self._res_st = saved
+            st = dict(st)
+            st.pop("_n_conns", None)
+            if self.mesh is not None:
+                st = self._mesh_put(st)
+            out = self._span_call(
+                self._fn,
+                st, self._lat, self._thr, self._node,
+                self._ips_sorted, self._ips_perm,
+                np.uint32(self._k[0]), np.uint32(self._k[1]),
+                np.int64(self.bootstrap_end),
+                start, stop, limit, runahead, mr)
         self.overlap_windows += 1
-        if w is not None:
-            w.add("dispatch",
-                  _time.perf_counter_ns() - t0, t0)  # shadow-lint: allow[wall-clock] dispatch attribution (metrics.wall)
+        if t_ready is not None:
+            self._book_idle(disp.t1 - t_ready)
         return self._speculate_record(
-            out, t0, (start, stop, limit, runahead, bool(dynamic),
-                      mr))
+            out, disp.t0, (start, stop, limit, runahead, bool(dynamic),
+                           mr))

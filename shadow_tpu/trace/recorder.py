@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import time
 
 import numpy as np
@@ -172,6 +173,52 @@ class WallChannel:
             "events": [list(e) for e in self.events],
             "dropped_events": self.dropped_events,
         }
+
+
+def _annotation(name: str):
+    """A `jax.profiler.TraceAnnotation`, or None while JAX is not
+    imported (no profiler session can be active then, and a serial
+    run must not import JAX for it)."""
+    if "jax" not in sys.modules:
+        return None
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(name)
+
+
+class Span:
+    """Context manager for one lexically scoped wall phase.  With a
+    channel it books the interval as phase `name` exactly as
+    `wall.add(name, ns, t0)` would, and mirrors it as a
+    `jax.profiler.TraceAnnotation` on the profiler's host plane (a
+    no-op unless a profiler session is active).  `t0`/`t1`
+    (perf_counter ns) are measured with or without a channel: the span
+    runners' always-on dispatch counters read them."""
+
+    __slots__ = ("wall", "name", "t0", "t1", "_ann")
+
+    def __init__(self, wall: WallChannel | None, name: str):
+        self.wall = wall
+        self.name = name
+        self._ann = None
+
+    @property
+    def ns(self) -> int:
+        return self.t1 - self.t0
+
+    def __enter__(self) -> "Span":
+        if self.wall is not None:
+            self._ann = _annotation(self.name)
+            if self._ann is not None:
+                self._ann.__enter__()
+        self.t0 = time.perf_counter_ns()  # shadow-lint: allow[wall-clock] wall-time channel + dispatch attribution (metrics.wall)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = time.perf_counter_ns()  # shadow-lint: allow[wall-clock] wall-time channel + dispatch attribution (metrics.wall)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self.wall is not None:
+            self.wall.add(self.name, self.t1 - self.t0, self.t0)
 
 
 class FlightRecorder:

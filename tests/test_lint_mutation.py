@@ -207,8 +207,8 @@ def test_trace_event_enum_reorder_is_caught(cpp_text):
 def test_unregistered_trace_enum_fails_closed(cpp_text):
     """A new EL_* reason added engine-side without a contract row (and
     a Python twin) must fail the pass, not silently under-check."""
-    mutated = _mutate(cpp_text, "EL_ENGINE_UNSHARDED, EL_N,",
-                      "EL_ENGINE_UNSHARDED, EL_ROGUE, EL_N,")
+    mutated = _mutate(cpp_text, "EL_SVC_QUIESCENT, EL_N,",
+                      "EL_SVC_QUIESCENT, EL_ROGUE, EL_N,")
     v = twin_constants.check(ROOT, cpp_text=mutated)
     msgs = [x.message for x in v]
     assert any("EL_ROGUE" in m and "no contract row" in m
@@ -500,8 +500,8 @@ def test_el_shard_enum_drift_is_caught(cpp_text):
     # attribution (missing registered twin + unregistered EL_ member,
     # both fail-closed)
     mutated = _mutate(cpp_text,
-                      "EL_ENGINE_EXCHANGE, EL_ENGINE_UNSHARDED, EL_N",
-                      "EL_ENGINE_EXCHANGE2, EL_ENGINE_UNSHARDED, EL_N")
+                      "EL_ENGINE_EXCHANGE, EL_ENGINE_UNSHARDED,",
+                      "EL_ENGINE_EXCHANGE2, EL_ENGINE_UNSHARDED,")
     v = twin_constants.check(ROOT, cpp_text=mutated)
     assert any("EL_ENGINE_EXCHANGE" in x.message for x in v), \
         [x.render() for x in v]
@@ -584,18 +584,17 @@ def test_ks_stage_name_table_reorder_is_caught(cpp_text):
 def test_async_hazard_bites_on_real_dispatch_loop(tmp_path):
     """Pass-3 async-hazard (ISSUE 16), real-tree mutation: an engine
     mutation slipped between the grow loop's raw `_span_call` dispatch
-    and its np.asarray force in ops/phold_span.py must flag — the
-    window's basis would drift with no landing check to catch it."""
+    and its block_until_ready force in ops/phold_span.py must flag —
+    the window's basis would drift with no landing check to catch it."""
     from shadow_tpu.analysis import determinism
     path = os.path.join(ROOT, "shadow_tpu", "ops", "phold_span.py")
     with open(path) as fh:
         src = fh.read()
-    anchor = ("            (st_out, next_start, ra, rounds, "
-              "busy_rounds, packets,\n"
-              "             busy_end, span_iters) = out\n")
+    anchor = ("                    jax.block_until_ready(out)\n"
+              "                if fresh_fn:\n")
     mutated = _mutate(
         src, anchor,
-        "            self.engine.run_until(0)\n" + anchor)
+        "                    self.engine.run_until(0)\n" + anchor)
     mpath = tmp_path / "phold_span.py"
     mpath.write_text(mutated)
     v = determinism.check(ROOT, paths=[str(mpath)])
