@@ -37,7 +37,7 @@ import numpy as np
 
 from shadow_tpu.core.rng import STREAM_PACKET_LOSS, mix_key, threefry2x32_jax
 from shadow_tpu.core.simtime import TIME_NEVER
-from shadow_tpu.ops.span_mesh import SpanMeshMixin
+from shadow_tpu.ops.span_mesh import SpanMeshMixin, scatter_set
 from shadow_tpu.trace.events import KS_NAMES
 from shadow_tpu.trace.recorder import Span
 
@@ -562,14 +562,10 @@ class PholdSpanRunner(SpanMeshMixin):
             mask = mask & ~overflow
             rows = mrows(mask)
             st = dict(st)
-            for key, v in (("th_time", time), ("th_seq", seq)):
-                st[key] = st[key].at[rows, free].set(v, mode="drop")
-            st["th_kind"] = st["th_kind"].at[rows, free].set(
-                kind, mode="drop")
-            st["th_tgt"] = st["th_tgt"].at[rows, free].set(
-                tgt, mode="drop")
-            st["th_valid"] = st["th_valid"].at[rows, free].set(
-                True, mode="drop")
+            for key, v in (("th_time", time), ("th_seq", seq),
+                           ("th_kind", kind), ("th_tgt", tgt),
+                           ("th_valid", True)):
+                st[key] = scatter_set(st[key], (rows, free), v)
             return mark_abort(st, overflow.any(), AB_STRUCT)
 
         def th_min(st):
@@ -604,7 +600,7 @@ class PholdSpanRunner(SpanMeshMixin):
             rank = jnp.cumsum(mask) - 1
             slot = jnp.where(mask, n + rank, cap_total + 8)
             for key, v in cols.items():
-                st[key] = st[key].at[slot].set(v, mode="drop")
+                st[key] = scatter_set(st[key], slot, v)
             total = n + mask.sum()
             st[count_key] = total
             return mark_abort(st, total > cap_total - H, abort_bit)
@@ -823,7 +819,7 @@ class PholdSpanRunner(SpanMeshMixin):
                     codel_drop, st["app_pkts_dropped"] + 1,
                     st["app_pkts_dropped"])
                 st["drop_causes"] = st["drop_causes"].at[
-                    mrows(codel_drop), TEL_CODEL].add(1, mode="drop")
+                    :, TEL_CODEL].add(codel_drop.astype(jnp.int64))
                 st = tr_append(st, codel_drop, now, TR_DRP, pk, 1)
                 st = dict(st)
                 # dropped lanes stay in the drain (next micro-op
@@ -867,7 +863,7 @@ class PholdSpanRunner(SpanMeshMixin):
                     linkdn, st["app_pkts_dropped"] + 1,
                     st["app_pkts_dropped"])
                 st["drop_causes"] = st["drop_causes"].at[
-                    mrows(linkdn), TEL_LINK_DOWN].add(1, mode="drop")
+                    :, TEL_LINK_DOWN].add(linkdn.astype(jnp.int64))
                 st = tr_append(st, linkdn, now, TR_DRP, pk,
                                RSN_LINKDOWN)
                 st = dict(st)
@@ -877,7 +873,7 @@ class PholdSpanRunner(SpanMeshMixin):
                     miss, st["app_pkts_dropped"] + 1,
                     st["app_pkts_dropped"])
                 st["drop_causes"] = st["drop_causes"].at[
-                    mrows(miss), TEL_NO_ROUTE].add(1, mode="drop")
+                    :, TEL_NO_ROUTE].add(miss.astype(jnp.int64))
                 st = tr_append(st, miss, now, TR_DRP, pk, RSN_NOROUTE)
                 hit = fwd & found
                 st, sq = draw_seq(st, hit)
@@ -900,7 +896,7 @@ class PholdSpanRunner(SpanMeshMixin):
                     wrong, st["app_pkts_dropped"] + 1,
                     st["app_pkts_dropped"])
                 st["drop_causes"] = st["drop_causes"].at[
-                    mrows(wrong), TEL_NO_SOCKET].add(1, mode="drop")
+                    :, TEL_NO_SOCKET].add(wrong.astype(jnp.int64))
                 st = tr_append(st, wrong, now, TR_DRP, pk, RSN_NOSOCK)
                 st = dict(st)
                 deliver = fwd & ~wrong
@@ -910,7 +906,7 @@ class PholdSpanRunner(SpanMeshMixin):
                     full, st["app_pkts_dropped"] + 1,
                     st["app_pkts_dropped"])
                 st["drop_causes"] = st["drop_causes"].at[
-                    mrows(full), TEL_RECVBUF_FULL].add(1, mode="drop")
+                    :, TEL_RECVBUF_FULL].add(full.astype(jnp.int64))
                 st = tr_append(st, full, now, TR_DRP, pk, RSN_RCVBUF)
                 st = dict(st)
                 good = deliver & ~full
@@ -920,8 +916,8 @@ class PholdSpanRunner(SpanMeshMixin):
                 tail = st["rq_len"] % R
                 rows = mrows(good)
                 for kk in PK_KEYS:
-                    st[f"rq_{kk}"] = st[f"rq_{kk}"].at[rows, tail].set(
-                        pk[kk], mode="drop")
+                    st[f"rq_{kk}"] = scatter_set(st[f"rq_{kk}"],
+                                                 (rows, tail), pk[kk])
                 st["rq_len"] = jnp.where(good, st["rq_len"] + 1,
                                          st["rq_len"])
                 st["recv_bytes"] = jnp.where(
@@ -982,8 +978,8 @@ class PholdSpanRunner(SpanMeshMixin):
                     "sport": st["m_port"], "dip": st[tgt_k],
                     "dport": st["m_port"]}
             for kk in PK_KEYS:
-                st[f"sq_{kk}"] = st[f"sq_{kk}"].at[rows, tail].set(
-                    vals[kk], mode="drop")
+                st[f"sq_{kk}"] = scatter_set(st[f"sq_{kk}"],
+                                             (rows, tail), vals[kk])
             st["sq_len"] = jnp.where(sent, st["sq_len"] + 1,
                                      st["sq_len"])
             st["send_bytes"] = jnp.where(
@@ -1082,8 +1078,8 @@ class PholdSpanRunner(SpanMeshMixin):
                         "sip": st["eth_ip"], "sport": st["m_port"],
                         "dip": pick, "dport": st["m_port"]}
                 for kk in PK_KEYS:
-                    st[f"sq_{kk}"] = st[f"sq_{kk}"].at[rows, tail].set(
-                        vals[kk], mode="drop")
+                    st[f"sq_{kk}"] = scatter_set(st[f"sq_{kk}"],
+                                                 (rows, tail), vals[kk])
                 st["sq_len"] = jnp.where(sent, st["sq_len"] + 1,
                                          st["sq_len"])
                 st["send_bytes"] = jnp.where(
@@ -1272,11 +1268,9 @@ class PholdSpanRunner(SpanMeshMixin):
                 arr_f, st["app_pkts_dropped"] + 1,
                 st["app_pkts_dropped"])
             st["drop_causes"] = st["drop_causes"].at[
-                mrows(arr_f & h_down), TEL_HOST_DOWN].add(
-                1, mode="drop")
+                :, TEL_HOST_DOWN].add((arr_f & h_down).astype(jnp.int64))
             st["drop_causes"] = st["drop_causes"].at[
-                mrows(arr_f & ~h_down), TEL_LINK_DOWN].add(
-                1, mode="drop")
+                :, TEL_LINK_DOWN].add((arr_f & ~h_down).astype(jnp.int64))
             st = tr_append(st, arr_f & h_down, et, TR_DRP, pk_arr,
                            RSN_HOSTDOWN)
             st = tr_append(st, arr_f & ~h_down, et, TR_DRP, pk_arr,
@@ -1305,7 +1299,7 @@ class PholdSpanRunner(SpanMeshMixin):
                 limit_full, st["app_pkts_dropped"] + 1,
                 st["app_pkts_dropped"])
             st["drop_causes"] = st["drop_causes"].at[
-                mrows(limit_full), TEL_RTR_LIMIT].add(1, mode="drop")
+                :, TEL_RTR_LIMIT].add(limit_full.astype(jnp.int64))
             st = tr_append(st, limit_full, et, TR_DRP, pk_arr, 2)
             st = dict(st)
             arr = arr & ~limit_full
@@ -1315,10 +1309,9 @@ class PholdSpanRunner(SpanMeshMixin):
             tail = st["cq_len"] % C
             rows = mrows(arr)
             for kk in PK_KEYS:
-                st[f"cq_{kk}"] = st[f"cq_{kk}"].at[rows, tail].set(
-                    st[f"ib_{kk}"][hidx, safe], mode="drop")
-            st["cq_enq"] = st["cq_enq"].at[rows, tail].set(
-                et, mode="drop")
+                st[f"cq_{kk}"] = scatter_set(st[f"cq_{kk}"], (rows, tail),
+                                             st[f"ib_{kk}"][hidx, safe])
+            st["cq_enq"] = scatter_set(st["cq_enq"], (rows, tail), et)
             st["cq_len"] = jnp.where(arr, st["cq_len"] + 1,
                                      st["cq_len"])
             st["codel_peak"] = jnp.maximum(
@@ -1491,10 +1484,12 @@ class PholdSpanRunner(SpanMeshMixin):
                     (valid & ~reachable, RSN_UNREACH, TEL_UNREACHABLE),
                     (valid & reachable & lossy, RSN_LOSS,
                      TEL_LOSS_EDGE)):
-                st["app_pkts_dropped"] = st["app_pkts_dropped"].at[
+                # Rows repeat, so count in 32 bits (at most O per
+                # round) and add densely: no 64-bit scatter-add.
+                cnt = jnp.zeros(H, jnp.int32).at[
                     jnp.where(miss, src, OOB)].add(1, mode="drop")
-                st["drop_causes"] = st["drop_causes"].at[
-                    jnp.where(miss, src, OOB), tel].add(1, mode="drop")
+                st["app_pkts_dropped"] = st["app_pkts_dropped"] + cnt
+                st["drop_causes"] = st["drop_causes"].at[:, tel].add(cnt)
                 if tracing:
                     nt_ = st["tr_n"]
                     rank = jnp.cumsum(miss) - 1
@@ -1511,7 +1506,7 @@ class PholdSpanRunner(SpanMeshMixin):
                             ("tr_reason",
                              jnp.full(O, rsn, jnp.int32)),
                             ("tr_owner", src)):
-                        st[key] = st[key].at[slot].set(v, mode="drop")
+                        st[key] = scatter_set(st[key], slot, v)
                     tot = nt_ + miss.sum()
                     st["tr_n"] = tot
                     st = mark_abort(st, tot > TR - O, AB_TRACE)
@@ -1582,12 +1577,11 @@ class PholdSpanRunner(SpanMeshMixin):
                             AB_STRUCT)
             st = dict(st)
             rows = jnp.where(ok_slot, d_dst, OOB)
-            ib_time = ib_time.at[rows, slot].set(d_time, mode="drop")
-            ib_src = ib_src.at[rows, slot].set(d_src, mode="drop")
-            ib_seq = ib_seq.at[rows, slot].set(d_seq, mode="drop")
+            ib_time = scatter_set(ib_time, (rows, slot), d_time)
+            ib_src = scatter_set(ib_src, (rows, slot), d_src)
+            ib_seq = scatter_set(ib_seq, (rows, slot), d_seq)
             for kk in PK_KEYS:
-                ib_pk[kk] = ib_pk[kk].at[rows, slot].set(d_pk[kk],
-                                                         mode="drop")
+                ib_pk[kk] = scatter_set(ib_pk[kk], (rows, slot), d_pk[kk])
             add = jnp.zeros(H, jnp.int32).at[rows].add(1, mode="drop")
             sort_idx = jnp.lexsort((ib_seq, ib_src, ib_time), axis=1)
             take = jnp.take_along_axis

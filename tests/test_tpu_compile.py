@@ -8,6 +8,8 @@ topology is described inside a module fixture (never at import, in a
 at a time, so only the worker that runs this file loads it.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -120,6 +122,19 @@ def test_phold_span_kernel_compiles(monkeypatch, one_chip):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.output_size_in_bytes \
         + mem.temp_size_in_bytes < 16e9, "does not fit a v5e's 16 GB"
+    # A 64-bit indexed write lowers to one scatter over a (u32, u32)
+    # pair, 12-16 times the cost of two 32-bit ones (span_mesh.py
+    # scatter_set): the module must hold no tuple-result scatter.
+    pairs = []
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT\s+)?%?(\S+)\s*=\s*\([^=]*\)\s*scatter\(",
+                     line)
+        if m:
+            op = re.search(r'op_name="([^"]*)"', line)
+            pairs.append(f"{m.group(1)} "
+                         f"({op.group(1) if op else 'no op_name'})")
+    assert not pairs, (f"{len(pairs)} tuple-result scatters: "
+                       + "; ".join(pairs[:10]))
 
 
 @pytest.mark.parametrize("kernel", ["bucket_step", "codel_head"])
