@@ -20,6 +20,11 @@ def _indent(text: str, pad: str) -> str:
     return "\n".join(pad + line for line in text.splitlines())
 
 
+def n_hosts(config: dict) -> int:
+    """How many hosts the generated configuration holds: one per LP."""
+    return config["params"]["n_lps"]
+
+
 def lcg_seed(j: int) -> int:
     """Logical LP j's LCG seed argument, below 2**31."""
     return (j * 7_919 + 1) % (1 << 31)

@@ -21,7 +21,7 @@ def test_config_found_by_name(name):
     assert entry["file"] == f"benchmark/configs/{name}.json"
     assert cfg["name"] == name and cfg["source"] == entry["source"]
     assert set(entry["reduced"]) == set(cfg["reduced"])
-    for key in ("assumed", "guarantees", "control", "rehearse"):
+    for key in ("assumed", "guarantees", "control", "rehearse", "agree"):
         assert cfg[key]
     registry.generator(cfg["generator"])
     assert callable(registry.reference(cfg["reference"]).compare)
@@ -62,9 +62,9 @@ def test_generated_yaml_parses(name, rehearse):
     cfg = run.sizes_of(registry.config(c["config"]), rehearse)
     trf = registry.traffic(c["traffic"])
     exp = {**cfg["experimental"], **trf["experimental"]}
-    text = registry.generator(cfg["generator"]).make_yaml(
-        cfg, trf, 2**31 + 12345, "tpu", exp)
+    gen = registry.generator(cfg["generator"])
+    text = gen.make_yaml(cfg, trf, 2**31 + 12345, "tpu", exp)
     opts = ConfigOptions.from_yaml_text(text)
     assert opts.general.seed == 2**31 + 12345
     assert opts.experimental.scheduler == "tpu"
-    assert len(opts.hosts) == cfg["params"]["n_lps"]
+    assert len(opts.hosts) == gen.n_hosts(cfg)
