@@ -398,8 +398,11 @@ constexpr int KS_INET_OUT = 8;   /* inet-out relay drain (r1) */
 constexpr int KS_ARM = 9;        /* timer-arm / status tail */
 constexpr int KS_TIMERS = 10;    /* timer handling */
 constexpr int KS_EXCHANGE = 11;  /* sharded cross-shard staging hop */
-constexpr int KS_N = 12;
-constexpr int KS_REC_BYTES = 224; /* trace/events.py KS_REC "<qiiqq24q" */
+constexpr int KS_PROBE = 12;     /* memberlist timers and probing */
+constexpr int KS_GOSSIP = 13;    /* memberlist gossip sends */
+constexpr int KS_MERGE = 14;     /* memberlist updates applied */
+constexpr int KS_N = 15;
+constexpr int KS_REC_BYTES = 272; /* trace/events.py KS_REC "<qiiqq30q" */
 
 /* Order mirrors the KS_* enum (and trace/events.py KS_NAMES). */
 [[maybe_unused]] static const char *KS_NAMES[KS_N] = {
@@ -415,6 +418,9 @@ constexpr int KS_REC_BYTES = 224; /* trace/events.py KS_REC "<qiiqq24q" */
     "arm",
     "timers",
     "exchange",
+    "probe",
+    "gossip",
+    "merge",
 };
 
 /* engine -> Python callback kinds */
@@ -1927,6 +1933,8 @@ struct AppN {
    * per-thread completion flags for the joint process exit */
   std::vector<uint32_t> peers;
   int32_t mesh_peer = -1;
+  /* memberlist: index into Engine::mls (the member's SWIM state) */
+  int32_t ml = -1;
   bool part_done = false;
   /* Job control (Process.stop_process twin): while stopped the
    * steppers consume no events — a wake that fires parks instead
@@ -1952,7 +1960,202 @@ struct AppN {
 constexpr int APP_SERVER = 0, APP_CLIENT = 1, APP_HANDLER = 2,
               APP_UDP_FLOOD = 3, APP_UDP_SINK = 4, APP_UDP_MESH = 5,
               APP_UDP_MESH_SND = 6, APP_PHOLD = 7, APP_PHOLD_SEED = 8,
-              APP_UDP_ECHO = 9, APP_UDP_PING = 10;
+              APP_UDP_ECHO = 9, APP_UDP_PING = 10, APP_MEMBERLIST = 11;
+
+/* ---------------- memberlist (SWIM + Lifeguard) ------------------ */
+/* APP_MEMBERLIST runs one member of a memberlist LAN pool (HashiCorp
+ * memberlist's DefaultLANConfig: SWIM, Das, Gupta & Motivala, DSN
+ * 2002, with Lifeguard, arXiv:1707.00788) over one UDP socket, with
+ * its view of every member.  Its device twin is ops/phold_span.py
+ * family 2; docs/PARITY.md "memberlist" lists the departures from
+ * memberlist itself.  Every wake (a protocol timer or a datagram)
+ * runs the due protocol timers in (deadline, class, key) order, then
+ * reads every queued datagram in arrival order, then arms one TK_APP
+ * at the earliest protocol deadline if that is earlier than the one
+ * armed. */
+constexpr int ML_ALIVE = 0, ML_SUSPECT = 1, ML_DEAD = 2, ML_REAPED = 3;
+constexpr int MLM_PING = 1, MLM_PINGREQ = 2, MLM_ACK = 3, MLM_NACK = 4,
+              MLM_SUSPECT = 5, MLM_DEAD = 6, MLM_ALIVE = 7;
+constexpr int64_t ML_NEVER = 1LL << 62;
+constexpr int64_t ML_PROBE_IV = 1000000000, ML_PROBE_TO = 500000000,
+                  ML_GOSSIP_IV = 200000000, ML_G2DEAD = 30000000000LL;
+constexpr int ML_INDIRECT = 3, ML_GOSSIP_NODES = 3, ML_RETX_MULT = 4,
+              ML_SUSP_MULT = 4, ML_SUSP_MAX_MULT = 6, ML_SUSP_K = 2,
+              ML_AWARE_MAX = 8, ML_UDP_BUF = 1400;
+/* threefry draw kinds (counter word 0 = member | kind << 20) */
+constexpr uint32_t ML_DRAW_STAGGER = 0, ML_DRAW_SHUFFLE = 1,
+                   ML_DRAW_PICK = 2;
+/* per-member protocol counters (codec column ml_cnt; named in
+ * ops/memberlist_span.py MLC_NAMES) */
+enum {
+  MLC_PING = 0, MLC_ACK, MLC_PINGREQ, MLC_NACK, MLC_SUSPECT, MLC_DEAD,
+  MLC_ALIVE, MLC_UPD_SENT, MLC_UPD_DROPPED, MLC_N
+};
+
+/* msgpack length of a positive integer */
+inline int ml_uint_len(uint32_t v) {
+  return v < 128 ? 1 : v < 256 ? 2 : v < 65536 ? 3 : 5;
+}
+/* Encoded length of one message: a type byte plus the msgpack map of
+ * memberlist's struct, keyed by field name, with 6-byte node names
+ * (m%05d), 4-byte addresses, port 7946, empty Meta and Payload and a
+ * 6-byte Vsn.  `a` is the SeqNo or the Incarnation. */
+inline int ml_msg_len(int type, uint32_t a) {
+  int u = ml_uint_len(a);
+  switch (type) {
+    case MLM_PING: return 68 + u;
+    case MLM_PINGREQ: return 94 + u;
+    case MLM_ACK: return 17 + u;
+    case MLM_NACK: return 8 + u;
+    case MLM_ALIVE: return 61 + u;
+    default: return 38 + u;  // suspect, dead
+  }
+}
+
+/* One message of a datagram: ping {seq, target}, ping-req {seq,
+ * target, nack}, ack / nack {seq}, suspect / dead {inc, node, from},
+ * alive {inc, node}. */
+struct MlPart {
+  uint8_t type = 0;
+  uint32_t a = 0, b = 0, c = 0;
+};
+struct MlBcast {
+  int32_t node, from, tx, len;
+  uint8_t kind;
+  uint32_t inc;
+  int64_t id;
+};
+struct MlRelay {
+  uint32_t seq, oseq;
+  int32_t from;
+  uint8_t nack;
+  int64_t dl;
+};
+struct MlSusp {
+  int32_t node, from, n0, k, c;
+  int32_t conf[ML_SUSP_K];
+  int64_t start, dl;
+};
+struct MlDead {
+  int32_t node;
+  int64_t t;
+};
+
+struct MlState {
+  int32_t self = 0, N = 0, n = 0, score = 0, pidx = 0;
+  uint64_t key = 0;
+  uint32_t inc = 0, seq = 0, ctr_shuf = 0, ctr_pick = 0;
+  std::vector<uint8_t> vstate;   // ML_ALIVE.. per member
+  std::vector<uint32_t> vinc;    // incarnation per member
+  std::vector<uint16_t> order;   // probe order
+  int64_t probe_next = 0, gossip_next = 0, armed = ML_NEVER;
+  uint8_t tick_pending = 0;
+  /* the probe in flight */
+  uint8_t p_active = 0;
+  int32_t p_target = 0, p_exp = 0, p_nacks = 0;
+  uint32_t p_seq = 0, p_inc = 0;
+  int64_t p_to = ML_NEVER, p_dl = ML_NEVER;
+  std::vector<MlBcast> bq;
+  int64_t bq_id = 0;
+  std::vector<MlRelay> relays;
+  std::vector<MlSusp> susp;
+  std::vector<MlDead> dead;
+  int64_t cnt[MLC_N] = {0};
+};
+
+/* The pool's shared tables: member -> address, and the size-derived
+ * timeouts and limits for every member count n in [0, N]. */
+struct MlShared {
+  int32_t N = 0;
+  std::vector<uint32_t> ips;
+  std::unordered_map<uint32_t, int32_t> by_ip;
+  std::vector<int64_t> sus_min;   // suspicionTimeout(4, n, 1 s)
+  std::vector<int64_t> sus_conf;  // [n * 3 + c]: timeout after c confirmations
+  std::vector<int32_t> retx;      // retransmitLimit(4, n)
+  static double seconds(int64_t d) {  // Go's Duration.Seconds()
+    return (double)(d / 1000000000) + (double)(d % 1000000000) / 1e9;
+  }
+  void init(const uint32_t *p, int64_t n_ips) {
+    N = (int32_t)n_ips;
+    ips.assign(p, p + n_ips);
+    for (int32_t j = 0; j < N; j++) by_ip[ips[(size_t)j]] = j;
+    sus_min.assign((size_t)N + 1, 0);
+    sus_conf.assign(((size_t)N + 1) * 3, 0);
+    retx.assign((size_t)N + 1, 0);
+    for (int32_t n = 0; n <= N; n++) {
+      double scale = std::max(1.0, std::log10(std::max(1.0, (double)n)));
+      int64_t mn = (int64_t)ML_SUSP_MULT * (int64_t)(scale * 1000) *
+                   ML_PROBE_IV / 1000;
+      int64_t mx = (int64_t)ML_SUSP_MAX_MULT * mn;
+      sus_min[(size_t)n] = mn;
+      for (int c = 1; c <= ML_SUSP_K; c++) {
+        double frac = std::log((double)c + 1.0) /
+                      std::log((double)ML_SUSP_K + 1.0);
+        double raw = seconds(mx) - frac * (seconds(mx) - seconds(mn));
+        int64_t t = (int64_t)std::floor(1000.0 * raw) * 1000000;
+        sus_conf[(size_t)n * 3 + (size_t)c] = t < mn ? mn : t;
+      }
+      retx[(size_t)n] = ML_RETX_MULT *
+                        (int32_t)std::ceil(std::log10((double)(n + 1)));
+    }
+  }
+};
+
+/* Datagram bytes: a part count, then each part's type and fields
+ * (little-endian u32), zero-padded to the message's encoded length,
+ * which always holds them. */
+inline void ml_encode(const std::vector<MlPart> &parts, int64_t size,
+                      std::string *out) {
+  out->assign((size_t)size, '\0');
+  uint8_t *p = (uint8_t *)&(*out)[0];
+  size_t o = 0;
+  auto put = [&](uint32_t v) {
+    memcpy(p + o, &v, 4);
+    o += 4;
+  };
+  p[o++] = (uint8_t)parts.size();
+  for (const MlPart &x : parts) {
+    p[o++] = x.type;
+    put(x.a);
+    if (x.type == MLM_PING || x.type == MLM_ALIVE) {
+      put(x.b);
+    } else if (x.type == MLM_PINGREQ) {
+      put(x.b);
+      p[o++] = (uint8_t)x.c;
+    } else if (x.type == MLM_SUSPECT || x.type == MLM_DEAD) {
+      put(x.b);
+      put(x.c);
+    }
+  }
+}
+inline bool ml_decode(const std::string &d, std::vector<MlPart> *out) {
+  const uint8_t *p = (const uint8_t *)d.data();
+  size_t o = 0, n = d.size();
+  auto get = [&](uint32_t *v) {
+    if (o + 4 > n) return false;
+    memcpy(v, p + o, 4);
+    o += 4;
+    return true;
+  };
+  if (n < 1) return false;
+  int np = p[o++];
+  for (int i = 0; i < np; i++) {
+    MlPart x;
+    if (o >= n) return false;
+    x.type = p[o++];
+    if (!get(&x.a)) return false;
+    if (x.type == MLM_PING || x.type == MLM_ALIVE) {
+      if (!get(&x.b)) return false;
+    } else if (x.type == MLM_PINGREQ) {
+      if (!get(&x.b) || o >= n) return false;
+      x.c = p[o++];
+    } else if (x.type == MLM_SUSPECT || x.type == MLM_DEAD) {
+      if (!get(&x.b) || !get(&x.c)) return false;
+    }
+    out->push_back(x);
+  }
+  return true;
+}
 /* client transfer states */
 constexpr int CL_CONNECTING = 1, CL_RECV = 3;
 /* handler states */
@@ -2210,6 +2413,10 @@ struct Engine {
   uint64_t state_epoch = 0;
   StableVec<std::unique_ptr<SocketN>> socks;  // token -> socket
   StableVec<AppN> apps;                       // engine-resident apps
+  /* memberlist members' SWIM state (AppN::ml) and the pool's shared
+   * address table and size-derived timeouts */
+  std::vector<std::unique_ptr<MlState>> mls;
+  MlShared ml;
 
   /* Fixed-record flight ring (set_flight / flight_take): per-round
    * milestones recorded while run_span iterates, drained by the
@@ -3185,6 +3392,43 @@ struct Engine {
         hp->tpush({now, hp->event_seq++, TK_APP, (uint32_t)sidx});
         app_step_phold(aidx, now);
       }
+    } else if (kind == APP_MEMBERLIST) {
+      /* memberlist <port> <member> <name pattern> <count> <key>: the
+       * pool's addresses ride the trailing u32 buffer, resolved once
+       * into the shared table.  The pool has formed: every member
+       * alive at incarnation 0 in every view. */
+      if (ml.N == 0) ml.init(peer_ips, n_peers);
+      auto mp = std::make_unique<MlState>();
+      MlState &m = *mp;
+      m.self = (int32_t)b;
+      m.N = ml.N;
+      m.n = ml.N;
+      m.key = (uint64_t)d;
+      m.vstate.assign((size_t)m.N, ML_ALIVE);
+      m.vinc.assign((size_t)m.N, 0);
+      m.order.resize((size_t)m.N);
+      /* the first walk runs the join order from the next member */
+      for (int32_t k = 0; k < m.N; k++)
+        m.order[(size_t)k] = (uint16_t)((m.self + 1 + k) % m.N);
+      m.probe_next = now + (int64_t)(ml_draw(m, ML_DRAW_STAGGER, 0) %
+                                     (uint32_t)ML_PROBE_IV);
+      m.gossip_next = now + (int64_t)(ml_draw(m, ML_DRAW_STAGGER, 1) %
+                                      (uint32_t)ML_GOSSIP_IV);
+      {
+        AppN &ap = apps[(size_t)aidx];
+        ap.port = (int)a;
+        ap.ml = (int32_t)mls.size();
+      }
+      mls.push_back(std::move(mp));
+      asys(hp, ASYS_SOCKET);
+      uint32_t tok = new_udp(hid, sb, rb);
+      sock(tok)->app_owner = aidx;
+      apps[(size_t)aidx].sock = (int64_t)tok;
+      asys(hp, ASYS_BIND);
+      if (generic_bind(hp, sock(tok), tok, 0, (int)a) < 0)
+        app_die(aidx, 101, now);
+      else
+        app_step_ml(aidx, now);
     } else if (kind == APP_UDP_ECHO) {
       AppN &ap = apps[(size_t)aidx];
       ap.port = (int)a;
@@ -3276,6 +3520,7 @@ struct Engine {
     else if (a.kind == APP_PHOLD_SEED) app_step_phold_seed(aidx, now);
     else if (a.kind == APP_UDP_ECHO) app_step_echo(aidx, now);
     else if (a.kind == APP_UDP_PING) app_step_ping(aidx, now);
+    else if (a.kind == APP_MEMBERLIST) app_step_ml(aidx, now);
     else app_step_handler(aidx, now);
   }
 
@@ -3890,6 +4135,474 @@ struct Engine {
       a.state = 0;
       // loop head closes + exits once sent_i reaches count
     }
+  }
+
+  /* ---- memberlist (SWIM + Lifeguard) member: see APP_MEMBERLIST */
+
+  uint32_t ml_draw(const MlState &m, uint32_t kind, uint32_t ctr) {
+    uint32_t o0, o1;
+    threefry2x32((uint32_t)m.key, (uint32_t)(m.key >> 32),
+                 (uint32_t)m.self | (kind << 20), ctr, &o0, &o1);
+    return o0;
+  }
+
+  int64_t ml_dead_since(const MlState &m, int32_t node) {
+    for (const MlDead &x : m.dead)
+      if (x.node == node) return x.t;
+    return ML_NEVER;
+  }
+
+  /* kRandomNodes: up to k distinct members in at most 3N draws,
+   * skipping self, `excl` and what the filter excludes: with
+   * `alive_only` every member not alive, else the reaped and those
+   * dead for longer than GossipToTheDeadTime. */
+  int ml_pick(MlState &m, int k, int32_t excl, bool alive_only,
+              int64_t now, int32_t *out) {
+    int got = 0;
+    for (int64_t i = 0; i < 3 * (int64_t)m.N && got < k; i++) {
+      int32_t j = (int32_t)(ml_draw(m, ML_DRAW_PICK, m.ctr_pick++) %
+                            (uint32_t)m.N);
+      if (j == m.self || j == excl) continue;
+      uint8_t st = m.vstate[(size_t)j];
+      if (alive_only ? st != ML_ALIVE
+                     : (st == ML_REAPED ||
+                        (st == ML_DEAD &&
+                         now - ml_dead_since(m, j) > ML_G2DEAD)))
+        continue;
+      bool dup = false;
+      for (int q = 0; q < got; q++) dup = dup || out[q] == j;
+      if (!dup) out[got++] = j;
+    }
+    return got;
+  }
+
+  /* TransmitLimitedQueue.QueueBroadcast: replaces any update queued
+   * about the same member. */
+  void ml_broadcast(MlState &m, uint8_t kind, uint32_t inc, int32_t node,
+                    int32_t from) {
+    for (size_t i = 0; i < m.bq.size(); i++)
+      if (m.bq[i].node == node) {
+        m.bq.erase(m.bq.begin() + (long)i);
+        break;
+      }
+    m.bq.push_back({node, from, 0, ml_msg_len(kind, inc), kind, inc,
+                    m.bq_id++});
+  }
+
+  /* TransmitLimitedQueue.GetBroadcasts(2, limit): fewest transmits
+   * first, then the longer, then the newer, while they fit; appends
+   * the parts and returns the bytes used (2 of overhead each). */
+  int64_t ml_get(MlState &m, int64_t limit, std::vector<MlPart> *parts) {
+    int64_t used = 0;
+    std::vector<size_t> taken;
+    int32_t lim = ml.retx[(size_t)m.n];
+    for (;;) {
+      int64_t free = limit - used - 2;
+      if (free <= 0) break;
+      long best = -1;
+      for (size_t i = 0; i < m.bq.size(); i++) {
+        const MlBcast &b = m.bq[i];
+        if (b.len > free) continue;
+        if (std::find(taken.begin(), taken.end(), i) != taken.end())
+          continue;
+        if (best < 0) { best = (long)i; continue; }
+        const MlBcast &o = m.bq[(size_t)best];
+        if (b.tx < o.tx || (b.tx == o.tx && (b.len > o.len ||
+            (b.len == o.len && b.id > o.id))))
+          best = (long)i;
+      }
+      if (best < 0) break;
+      const MlBcast &b = m.bq[(size_t)best];
+      taken.push_back((size_t)best);
+      used += 2 + b.len;
+      MlPart x;
+      x.type = b.kind;
+      x.a = b.inc;
+      x.b = (uint32_t)b.node;
+      x.c = (uint32_t)b.from;
+      parts->push_back(x);
+    }
+    for (size_t i : taken) {
+      m.cnt[MLC_UPD_SENT]++;
+      m.bq[i].tx++;
+    }
+    size_t w = 0;
+    for (size_t i = 0; i < m.bq.size(); i++) {
+      if (m.bq[i].tx >= lim) {
+        m.cnt[MLC_UPD_DROPPED]++;
+        continue;
+      }
+      m.bq[w++] = m.bq[i];
+    }
+    m.bq.resize(w);
+    return used;
+  }
+
+  void ml_send(int aidx, int32_t dst, const std::vector<MlPart> &parts,
+               int64_t size, int64_t now) {
+    AppN &a = apps[(size_t)aidx];
+    HostPlane *hp = plane(a.hid);
+    std::string buf;
+    ml_encode(parts, size, &buf);
+    asys(hp, ASYS_SENDTO);
+    /* a full send buffer loses the datagram (memberlist logs the
+     * send error and carries on) */
+    udp_sendto(hp, udp((uint32_t)a.sock), (uint32_t)a.sock, buf.data(),
+               size, 1, ml.ips[(size_t)dst], a.port, now);
+  }
+
+  /* encodeAndSendMsg: one message with what queued updates fit */
+  void ml_send_piggy(int aidx, MlState &m, int32_t dst, MlPart msg,
+                     int64_t now) {
+    int64_t len = ml_msg_len(msg.type, msg.a);
+    std::vector<MlPart> parts{msg};
+    int64_t used = ml_get(m, ML_UDP_BUF - len - 2, &parts);
+    ml_send(aidx, dst, parts,
+            parts.size() == 1 ? len : 2 + 2 + len + used, now);
+  }
+
+  void ml_aware(MlState &m, int delta) {
+    m.score = std::min(ML_AWARE_MAX - 1, std::max(0, m.score + delta));
+  }
+
+  void ml_refute(MlState &m, uint32_t accused) {
+    uint32_t inc = m.inc + 1;
+    if (accused >= inc) inc = accused + 1;
+    m.inc = inc;
+    m.vinc[(size_t)m.self] = inc;
+    ml_aware(m, 1);
+    ml_broadcast(m, MLM_ALIVE, inc, m.self, m.self);
+  }
+
+  void ml_del_susp(MlState &m, int32_t node) {
+    for (size_t i = 0; i < m.susp.size(); i++)
+      if (m.susp[i].node == node) {
+        m.susp.erase(m.susp.begin() + (long)i);
+        return;
+      }
+  }
+
+  void ml_del_dead(MlState &m, int32_t node) {
+    for (size_t i = 0; i < m.dead.size(); i++)
+      if (m.dead[i].node == node) {
+        m.dead.erase(m.dead.begin() + (long)i);
+        return;
+      }
+  }
+
+  void ml_suspect(MlState &m, uint32_t inc, int32_t node, int32_t from,
+                  int64_t now) {
+    uint8_t st = m.vstate[(size_t)node];
+    if (st == ML_REAPED || inc < m.vinc[(size_t)node]) return;
+    for (MlSusp &x : m.susp) {
+      if (x.node != node) continue;
+      /* suspicion.Confirm */
+      if (x.c >= x.k || from == x.from) return;
+      for (int q = 0; q < x.c; q++)
+        if (x.conf[q] == from) return;
+      x.conf[x.c++] = from;
+      int64_t dl = x.start + ml.sus_conf[(size_t)x.n0 * 3 + (size_t)x.c];
+      /* no time left: memberlist runs the timeout on a new goroutine */
+      x.dl = dl > now ? dl : now + 1;
+      ml_broadcast(m, MLM_SUSPECT, inc, node, from);
+      return;
+    }
+    if (st != ML_ALIVE) return;
+    if (node == m.self) {
+      ml_refute(m, inc);
+      return;
+    }
+    ml_broadcast(m, MLM_SUSPECT, inc, node, from);
+    m.cnt[MLC_SUSPECT]++;
+    m.vinc[(size_t)node] = inc;
+    m.vstate[(size_t)node] = ML_SUSPECT;
+    MlSusp x{};
+    x.node = node;
+    x.from = from;
+    x.n0 = m.n;
+    x.k = m.n - 2 < ML_SUSP_K ? 0 : ML_SUSP_K;
+    x.c = 0;
+    x.start = now;
+    int64_t mn = ml.sus_min[(size_t)m.n];
+    x.dl = now + (x.k < 1 ? mn : ML_SUSP_MAX_MULT * mn);
+    m.susp.push_back(x);
+  }
+
+  void ml_dead(MlState &m, uint32_t inc, int32_t node, int32_t from,
+               int64_t now) {
+    uint8_t st = m.vstate[(size_t)node];
+    if (st == ML_REAPED || inc < m.vinc[(size_t)node]) return;
+    ml_del_susp(m, node);
+    if (st == ML_DEAD) return;
+    if (node == m.self) {
+      ml_refute(m, inc);
+      return;
+    }
+    ml_broadcast(m, MLM_DEAD, inc, node, from);
+    m.cnt[MLC_DEAD]++;
+    m.vinc[(size_t)node] = inc;
+    m.vstate[(size_t)node] = ML_DEAD;
+    m.dead.push_back({node, now});
+  }
+
+  void ml_alive(MlState &m, uint32_t inc, int32_t node) {
+    if (node == m.self) {
+      if (inc <= m.vinc[(size_t)node]) return;
+      ml_refute(m, inc);
+      return;
+    }
+    if (inc <= m.vinc[(size_t)node]) return;
+    ml_del_susp(m, node);
+    ml_broadcast(m, MLM_ALIVE, inc, node, node);
+    m.cnt[MLC_ALIVE]++;
+    m.vinc[(size_t)node] = inc;
+    uint8_t st = m.vstate[(size_t)node];
+    if (st == ML_DEAD) ml_del_dead(m, node);
+    if (st == ML_REAPED) m.n++;
+    m.vstate[(size_t)node] = ML_ALIVE;
+  }
+
+  void ml_probe_node(int aidx, MlState &m, int32_t j, int64_t now) {
+    m.seq++;
+    m.p_active = 1;
+    m.p_target = j;
+    m.p_seq = m.seq;
+    m.p_inc = m.vinc[(size_t)j];
+    m.p_exp = 0;
+    m.p_nacks = 0;
+    m.p_to = now + ML_PROBE_TO;
+    m.p_dl = now + ML_PROBE_IV * (m.score + 1);
+    m.cnt[MLC_PING]++;
+    MlPart ping;
+    ping.type = MLM_PING;
+    ping.a = m.seq;
+    ping.b = (uint32_t)j;
+    if (m.vstate[(size_t)j] == ML_ALIVE) {
+      ml_send_piggy(aidx, m, j, ping, now);
+    } else {
+      /* the suspect member gets the accusation with the ping (the
+       * buddy system), sent as is */
+      MlPart sus;
+      sus.type = MLM_SUSPECT;
+      sus.a = m.p_inc;
+      sus.b = (uint32_t)j;
+      sus.c = (uint32_t)m.self;
+      std::vector<MlPart> parts{ping, sus};
+      ml_send(aidx, j, parts,
+              2 + 2 + ml_msg_len(MLM_PING, ping.a) + 2 +
+                  ml_msg_len(MLM_SUSPECT, sus.a),
+              now);
+    }
+  }
+
+  /* memberlist.probe(): walk the probe order, reaping and reshuffling
+   * when it wraps */
+  void ml_probe_start(int aidx, MlState &m, int64_t now) {
+    int64_t checks = 0;
+    for (;;) {
+      if (checks >= m.N) return;
+      if (m.pidx >= m.N) {
+        for (size_t i = 0; i < m.dead.size();) {
+          if (now - m.dead[i].t > ML_G2DEAD) {
+            m.vstate[(size_t)m.dead[i].node] = ML_REAPED;
+            m.n--;
+            m.dead.erase(m.dead.begin() + (long)i);
+          } else {
+            i++;
+          }
+        }
+        for (int32_t i = m.N - 1; i >= 1; i--) {
+          uint32_t j = ml_draw(m, ML_DRAW_SHUFFLE, m.ctr_shuf++) %
+                       (uint32_t)(i + 1);
+          std::swap(m.order[(size_t)i], m.order[(size_t)j]);
+        }
+        m.pidx = 0;
+        checks++;
+        continue;
+      }
+      int32_t j = m.order[(size_t)m.pidx++];
+      uint8_t st = m.vstate[(size_t)j];
+      if (j == m.self || st == ML_DEAD || st == ML_REAPED) {
+        checks++;
+        continue;
+      }
+      ml_probe_node(aidx, m, j, now);
+      return;
+    }
+  }
+
+  /* the probe ended (acked, or failed at its deadline): a tick that
+   * came meanwhile starts the next one now */
+  void ml_probe_end(int aidx, MlState &m, int delta, int64_t now) {
+    m.p_active = 0;
+    ml_aware(m, delta);
+    if (m.tick_pending) {
+      m.tick_pending = 0;
+      ml_probe_start(aidx, m, now);
+    }
+  }
+
+  /* Runs the earliest protocol timer due at `now`; false when none. */
+  bool ml_timer_one(int aidx, MlState &m, int64_t now) {
+    int64_t bt = ML_NEVER, bk = 0;
+    int bc = -1;
+    auto cand = [&](int64_t t, int c, int64_t k) {
+      if (t > now) return;
+      if (bc < 0 || t < bt ||
+          (t == bt && (c < bc || (c == bc && k < bk)))) {
+        bt = t;
+        bc = c;
+        bk = k;
+      }
+    };
+    cand(m.probe_next, 0, 0);
+    cand(m.gossip_next, 1, 0);
+    if (m.p_active) {
+      cand(m.p_to, 2, 0);
+      cand(m.p_dl, 3, 0);
+    }
+    for (const MlRelay &r : m.relays) cand(r.dl, 4, r.seq);
+    for (const MlSusp &x : m.susp) cand(x.dl, 5, x.node);
+    if (bc < 0) return false;
+    if (bc == 0) {
+      m.probe_next += ML_PROBE_IV;
+      if (m.p_active)
+        m.tick_pending = 1;  // Go's ticker holds one tick
+      else
+        ml_probe_start(aidx, m, now);
+    } else if (bc == 1) {
+      m.gossip_next += ML_GOSSIP_IV;
+      int32_t pk[ML_GOSSIP_NODES];
+      int np = ml_pick(m, ML_GOSSIP_NODES, -1, false, now, pk);
+      for (int q = 0; q < np; q++) {
+        std::vector<MlPart> parts;
+        int64_t used = ml_get(m, ML_UDP_BUF - 2, &parts);
+        if (parts.empty()) break;
+        ml_send(aidx, pk[q], parts,
+                parts.size() == 1 ? ml_msg_len(parts[0].type, parts[0].a)
+                                  : 2 + used,
+                now);
+      }
+    } else if (bc == 2) {
+      m.p_to = ML_NEVER;
+      int32_t pk[ML_INDIRECT];
+      int np = ml_pick(m, ML_INDIRECT, m.p_target, true, now, pk);
+      for (int q = 0; q < np; q++) {
+        MlPart req;
+        req.type = MLM_PINGREQ;
+        req.a = m.p_seq;
+        req.b = (uint32_t)m.p_target;
+        req.c = 1;
+        m.p_exp++;
+        m.cnt[MLC_PINGREQ]++;
+        ml_send_piggy(aidx, m, pk[q], req, now);
+      }
+    } else if (bc == 3) {
+      int delta = m.p_exp > 0 ? std::max(0, m.p_exp - m.p_nacks) : 1;
+      m.p_active = 0;
+      ml_suspect(m, m.p_inc, m.p_target, m.self, now);
+      ml_probe_end(aidx, m, delta, now);
+    } else if (bc == 4) {
+      for (size_t i = 0; i < m.relays.size(); i++) {
+        if (m.relays[i].seq != (uint32_t)bk) continue;
+        MlRelay r = m.relays[i];
+        m.relays.erase(m.relays.begin() + (long)i);
+        if (r.nack) {
+          MlPart nk;
+          nk.type = MLM_NACK;
+          nk.a = r.oseq;
+          m.cnt[MLC_NACK]++;
+          ml_send_piggy(aidx, m, r.from, nk, now);
+        }
+        break;
+      }
+    } else {
+      int32_t node = (int32_t)bk;
+      ml_del_susp(m, node);
+      ml_dead(m, m.vinc[(size_t)node], node, m.self, now);
+    }
+    return true;
+  }
+
+  void ml_handle(int aidx, MlState &m, int32_t from, const MlPart &x,
+                 int64_t now) {
+    if (x.type == MLM_PING) {
+      if ((int32_t)x.b != m.self) return;
+      MlPart ack;
+      ack.type = MLM_ACK;
+      ack.a = x.a;
+      m.cnt[MLC_ACK]++;
+      ml_send_piggy(aidx, m, from, ack, now);
+    } else if (x.type == MLM_PINGREQ) {
+      m.seq++;
+      m.relays.push_back({m.seq, x.a, from, (uint8_t)x.c,
+                          now + ML_PROBE_TO});
+      MlPart ping;
+      ping.type = MLM_PING;
+      ping.a = m.seq;
+      ping.b = x.b;
+      m.cnt[MLC_PING]++;
+      ml_send_piggy(aidx, m, (int32_t)x.b, ping, now);
+    } else if (x.type == MLM_ACK) {
+      if (m.p_active && x.a == m.p_seq) {
+        ml_probe_end(aidx, m, -1, now);
+        return;
+      }
+      for (size_t i = 0; i < m.relays.size(); i++) {
+        if (m.relays[i].seq != x.a) continue;
+        MlRelay r = m.relays[i];
+        m.relays.erase(m.relays.begin() + (long)i);
+        MlPart ack;
+        ack.type = MLM_ACK;
+        ack.a = r.oseq;
+        m.cnt[MLC_ACK]++;
+        ml_send_piggy(aidx, m, r.from, ack, now);
+        return;
+      }
+    } else if (x.type == MLM_NACK) {
+      if (m.p_active && x.a == m.p_seq) m.p_nacks++;
+    } else if (x.type == MLM_SUSPECT) {
+      ml_suspect(m, x.a, (int32_t)x.b, (int32_t)x.c, now);
+    } else if (x.type == MLM_DEAD) {
+      ml_dead(m, x.a, (int32_t)x.b, (int32_t)x.c, now);
+    } else if (x.type == MLM_ALIVE) {
+      ml_alive(m, x.a, (int32_t)x.b);
+    }
+  }
+
+  int64_t ml_next_deadline(const MlState &m) {
+    int64_t t = std::min(m.probe_next, m.gossip_next);
+    if (m.p_active) t = std::min(t, std::min(m.p_to, m.p_dl));
+    for (const MlRelay &r : m.relays) t = std::min(t, r.dl);
+    for (const MlSusp &x : m.susp) t = std::min(t, x.dl);
+    return t;
+  }
+
+  void app_step_ml(int aidx, int64_t now) {
+    AppN &a = apps[(size_t)aidx];
+    HostPlane *hp = plane(a.hid);
+    MlState &m = *mls[(size_t)a.ml];
+    if (m.armed <= now) m.armed = ML_NEVER;  // the armed wake is this one
+    while (ml_timer_one(aidx, m, now)) {
+    }
+    UdpSocketN *s = udp((uint32_t)a.sock);
+    for (;;) {
+      std::string data;
+      uint32_t sip;
+      int sport;
+      asys(hp, ASYS_RECVFROM);
+      if (udp_recvfrom(s, 65536, false, &data, &sip, &sport) < 0) break;
+      auto it = ml.by_ip.find(sip);
+      std::vector<MlPart> parts;
+      if (it == ml.by_ip.end() || !ml_decode(data, &parts)) continue;
+      for (const MlPart &x : parts) ml_handle(aidx, m, it->second, x, now);
+    }
+    int64_t nx = ml_next_deadline(m);
+    if (nx < m.armed) {
+      m.armed = nx;
+      hp->tpush({nx, hp->event_seq++, TK_APP, (uint32_t)aidx});
+    }
+    park(a, S_READABLE);
   }
 
   void app_step_handler(int aidx, int64_t now) {
@@ -4928,6 +5641,7 @@ struct Engine {
       else if (a.kind == APP_PHOLD_SEED) { f = 0; is_main = 0; }
       else if (a.kind == APP_UDP_MESH) { f = 1; is_main = 1; }
       else if (a.kind == APP_UDP_MESH_SND) { f = 1; is_main = 0; }
+      else if (a.kind == APP_MEMBERLIST) { f = 2; is_main = 1; }
       else return false;  // any other app: not a span-shaped sim
       if (fam < 0) fam = f;
       if (f != fam) return false;  // mixed families: keep it simple
@@ -4937,8 +5651,37 @@ struct Engine {
       slot[a.hid] = (int32_t)i;
     }
     sh->family = fam < 0 ? 0 : fam;
+    if (sh->family == 2) sh->pay_size = 0;  // sizes ride per packet
     for (size_t h = 0; h < H; h++) {
       HostPlane *hp = hosts[h].get();
+      if (sh->family == 2) {
+        /* memberlist: one app per member; a crashed member's host
+         * runs none and owns no socket */
+        if (sh->seed_idx[h] >= 0) return false;
+        if (hp->pcap_on[0] || hp->pcap_on[1]) return false;
+        if (hp->relays[0].state == RELAY_PENDING ||
+            hp->relays[0].pending != UINT64_MAX)
+          return false;
+        if (sh->main_idx[h] < 0) {
+          for (const TimerEnt &t : hp->theap)
+            if (t.kind != TK_RELAY || t.target == 0) return false;
+          continue;
+        }
+        AppN &m = apps[(size_t)sh->main_idx[h]];
+        if (m.stopped || m.exited || m.sock < 0 || m.ml < 0) return false;
+        UdpSocketN *u = udp((uint32_t)m.sock);
+        if (u == nullptr || u->has_peer || !u->has_local) return false;
+        if (!u->send_q[0].empty()) return false;
+        for (const TimerEnt &t : hp->theap) {
+          if (t.kind == TK_RELAY) {
+            if (t.target == 0) return false;
+          } else if (t.kind != TK_APP ||
+                     (int32_t)t.target != sh->main_idx[h]) {
+            return false;
+          }
+        }
+        continue;
+      }
       if (sh->main_idx[h] < 0 || sh->seed_idx[h] < 0) return false;
       AppN &m = apps[(size_t)sh->main_idx[h]];
       AppN &s = apps[(size_t)sh->seed_idx[h]];
@@ -5228,7 +5971,9 @@ struct Engine {
     std::vector<int64_t> pseq;
     std::vector<uint32_t> sip, dip;
     std::vector<int32_t> sport, dport;
+    std::vector<int32_t> plen;  // payload bytes (memberlist family)
     void push(const PacketN *p) {
+      plen.push_back((int32_t)p->payload.size());
       src_host.push_back(p->src_host);
       pseq.push_back((int64_t)p->seq);
       sip.push_back(p->src_ip);
@@ -5237,6 +5982,7 @@ struct Engine {
       dport.push_back(p->dst_port);
     }
     void push_empty() {
+      plen.push_back(0);
       src_host.push_back(0);
       pseq.push_back(0);
       sip.push_back(0);
@@ -6191,18 +6937,228 @@ static int dict_set(PyObject *d, const char *k, PyObject *v) {
   return r;
 }
 
+/* memberlist (family 2) columns of the device-span export: the shared
+ * tables, every member's SWIM state, its rare-entry tables up to their
+ * caps (the dense view rides span_export_ml_view), and a payload store that
+ * holds the parts of every datagram in flight at (sender, packet
+ * number % Q).  0 ok, 1 over a cap, -1 error. */
+static int ml_export(Engine *e, const Engine::PholdShape &sh, PyObject *d,
+                     long long BQ, long long RL, long long SU,
+                     long long DD, long long Q, long long NP) {
+  size_t H = e->hosts.size();
+  size_t N = (size_t)e->ml.N;
+  if (N == 0) return 1;
+  std::vector<int32_t> self(H, -1), n(H), score(H), pidx(H), p_target(H),
+      p_exp(H), p_nacks(H);
+  std::vector<uint32_t> inc(H), seq(H), ctr_shuf(H), ctr_pick(H),
+      p_seq(H), p_inc(H), key_lo(H), key_hi(H);
+  std::vector<int64_t> probe_next(H), gossip_next(H), armed(H), p_to(H),
+      p_dl(H), bq_id(H), cnt(H * MLC_N);
+  std::vector<uint8_t> tick(H), p_active(H);
+  std::vector<int32_t> bq_node(H * BQ, -1), bq_from(H * BQ), bq_tx(H * BQ),
+      bq_len(H * BQ);
+  std::vector<uint8_t> bq_kind(H * BQ);
+  std::vector<uint32_t> bq_inc(H * BQ);
+  std::vector<int64_t> bq_bid(H * BQ);
+  std::vector<uint32_t> rl_seq(H * RL), rl_oseq(H * RL);
+  std::vector<int32_t> rl_from(H * RL), rl_valid(H * RL);
+  std::vector<uint8_t> rl_nack(H * RL);
+  std::vector<int64_t> rl_dl(H * RL);
+  std::vector<int32_t> su_node(H * SU, -1), su_from(H * SU), su_n0(H * SU),
+      su_k(H * SU), su_c(H * SU), su_conf0(H * SU), su_conf1(H * SU);
+  std::vector<int64_t> su_start(H * SU), su_dl(H * SU);
+  std::vector<int32_t> dd_node(H * DD, -1);
+  std::vector<int64_t> dd_t(H * DD);
+  std::vector<int32_t> pay(H * Q * NP * 4, 0), pay_n(H * Q, 0);
+  std::vector<int64_t> pay_tag(H * Q, -1);
+  for (size_t h = 0; h < H; h++) {
+    if (sh.main_idx[h] < 0) continue;
+    const AppN &a = e->apps[(size_t)sh.main_idx[h]];
+    const MlState &m = *e->mls[(size_t)a.ml];
+    if ((long long)m.bq.size() > BQ || (long long)m.relays.size() > RL ||
+        (long long)m.susp.size() > SU || (long long)m.dead.size() > DD)
+      return 1;
+    self[h] = m.self;
+    n[h] = m.n;
+    score[h] = m.score;
+    pidx[h] = m.pidx;
+    inc[h] = m.inc;
+    seq[h] = m.seq;
+    ctr_shuf[h] = m.ctr_shuf;
+    ctr_pick[h] = m.ctr_pick;
+    key_lo[h] = (uint32_t)m.key;
+    key_hi[h] = (uint32_t)(m.key >> 32);
+    probe_next[h] = m.probe_next;
+    gossip_next[h] = m.gossip_next;
+    armed[h] = m.armed;
+    tick[h] = m.tick_pending;
+    p_active[h] = m.p_active;
+    p_target[h] = m.p_target;
+    p_exp[h] = m.p_exp;
+    p_nacks[h] = m.p_nacks;
+    p_seq[h] = m.p_seq;
+    p_inc[h] = m.p_inc;
+    p_to[h] = m.p_to;
+    p_dl[h] = m.p_dl;
+    bq_id[h] = m.bq_id;
+    for (int j = 0; j < MLC_N; j++) cnt[h * MLC_N + j] = m.cnt[j];
+    for (size_t j = 0; j < m.bq.size(); j++) {
+      const MlBcast &b = m.bq[j];
+      size_t k = h * BQ + j;
+      bq_node[k] = b.node;
+      bq_from[k] = b.from;
+      bq_tx[k] = b.tx;
+      bq_len[k] = b.len;
+      bq_kind[k] = b.kind;
+      bq_inc[k] = b.inc;
+      bq_bid[k] = b.id;
+    }
+    for (size_t j = 0; j < m.relays.size(); j++) {
+      const MlRelay &r = m.relays[j];
+      size_t k = h * RL + j;
+      rl_valid[k] = 1;
+      rl_seq[k] = r.seq;
+      rl_oseq[k] = r.oseq;
+      rl_from[k] = r.from;
+      rl_nack[k] = r.nack;
+      rl_dl[k] = r.dl;
+    }
+    for (size_t j = 0; j < m.susp.size(); j++) {
+      const MlSusp &x = m.susp[j];
+      size_t k = h * SU + j;
+      su_node[k] = x.node;
+      su_from[k] = x.from;
+      su_n0[k] = x.n0;
+      su_k[k] = x.k;
+      su_c[k] = x.c;
+      su_conf0[k] = x.conf[0];
+      su_conf1[k] = x.conf[1];
+      su_start[k] = x.start;
+      su_dl[k] = x.dl;
+    }
+    for (size_t j = 0; j < m.dead.size(); j++) {
+      dd_node[h * DD + j] = m.dead[j].node;
+      dd_t[h * DD + j] = m.dead[j].t;
+    }
+  }
+  /* every datagram in flight, wherever it waits */
+  std::vector<MlPart> parts;
+  auto stash = [&](uint64_t id) {
+    const PacketN *p = e->store.get(id);
+    if (p == nullptr) return 0;
+    parts.clear();
+    if (!ml_decode(p->payload, &parts) || (long long)parts.size() > NP)
+      return 1;
+    size_t slot = (size_t)p->src_host * Q + (size_t)(p->seq % (uint64_t)Q);
+    if (pay_tag[slot] >= 0 && pay_tag[slot] != (int64_t)p->seq) return 1;
+    pay_tag[slot] = (int64_t)p->seq;
+    pay_n[slot] = (int32_t)parts.size();
+    for (size_t j = 0; j < parts.size(); j++) {
+      int32_t *w = &pay[(slot * NP + j) * 4];
+      w[0] = parts[j].type;
+      w[1] = (int32_t)parts[j].a;
+      w[2] = (int32_t)parts[j].b;
+      w[3] = (int32_t)parts[j].c;
+    }
+    return 0;
+  };
+  for (size_t h = 0; h < H; h++) {
+    HostPlane *hp = e->hosts[h].get();
+    int bad = 0;
+    if (sh.main_idx[h] >= 0) {
+      UdpSocketN *u = e->udp((uint32_t)e->apps[(size_t)sh.main_idx[h]].sock);
+      for (uint64_t id : u->recv_q) bad |= stash(id);
+      for (uint64_t id : u->send_q[1]) bad |= stash(id);
+    }
+    for (auto &[id, enq] : hp->codel.q) bad |= stash(id);
+    for (int r = 1; r <= 2; r++)
+      if (hp->relays[r].pending != UINT64_MAX)
+        bad |= stash(hp->relays[r].pending);
+    for (const InboxEnt &ie : hp->inbox) bad |= stash(ie.pkt);
+    if (bad) return 1;
+  }
+  bool ok = true;
+  auto put = [&](const char *k, PyObject *v) {
+    if (dict_set(d, k, v) < 0) ok = false;
+  };
+  std::vector<uint32_t> ips(e->ml.ips);
+  std::vector<int64_t> sus_min(e->ml.sus_min), sus_conf(e->ml.sus_conf);
+  std::vector<int32_t> retx(e->ml.retx);
+  put("ml_self", bytes_vec(self));
+  put("ml_ips", bytes_vec(ips));
+  put("ml_sus_min", bytes_vec(sus_min));
+  put("ml_sus_conf", bytes_vec(sus_conf));
+  put("ml_retx", bytes_vec(retx));
+  put("ml_n", bytes_vec(n));
+  put("ml_score", bytes_vec(score));
+  put("ml_pidx", bytes_vec(pidx));
+  put("ml_inc", bytes_vec(inc));
+  put("ml_seq", bytes_vec(seq));
+  put("ml_ctr_shuf", bytes_vec(ctr_shuf));
+  put("ml_ctr_pick", bytes_vec(ctr_pick));
+  put("ml_key_lo", bytes_vec(key_lo));
+  put("ml_key_hi", bytes_vec(key_hi));
+  put("ml_probe_next", bytes_vec(probe_next));
+  put("ml_gossip_next", bytes_vec(gossip_next));
+  put("ml_armed", bytes_vec(armed));
+  put("ml_tick", bytes_vec(tick));
+  put("ml_p_active", bytes_vec(p_active));
+  put("ml_p_target", bytes_vec(p_target));
+  put("ml_p_exp", bytes_vec(p_exp));
+  put("ml_p_nacks", bytes_vec(p_nacks));
+  put("ml_p_seq", bytes_vec(p_seq));
+  put("ml_p_inc", bytes_vec(p_inc));
+  put("ml_p_to", bytes_vec(p_to));
+  put("ml_p_dl", bytes_vec(p_dl));
+  put("ml_bq_id", bytes_vec(bq_id));
+  put("ml_cnt", bytes_vec(cnt));
+  put("ml_bq_node", bytes_vec(bq_node));
+  put("ml_bq_from", bytes_vec(bq_from));
+  put("ml_bq_tx", bytes_vec(bq_tx));
+  put("ml_bq_len", bytes_vec(bq_len));
+  put("ml_bq_kind", bytes_vec(bq_kind));
+  put("ml_bq_inc", bytes_vec(bq_inc));
+  put("ml_bq_bid", bytes_vec(bq_bid));
+  put("ml_rl_valid", bytes_vec(rl_valid));
+  put("ml_rl_seq", bytes_vec(rl_seq));
+  put("ml_rl_oseq", bytes_vec(rl_oseq));
+  put("ml_rl_from", bytes_vec(rl_from));
+  put("ml_rl_nack", bytes_vec(rl_nack));
+  put("ml_rl_dl", bytes_vec(rl_dl));
+  put("ml_su_node", bytes_vec(su_node));
+  put("ml_su_from", bytes_vec(su_from));
+  put("ml_su_n0", bytes_vec(su_n0));
+  put("ml_su_k", bytes_vec(su_k));
+  put("ml_su_c", bytes_vec(su_c));
+  put("ml_su_conf0", bytes_vec(su_conf0));
+  put("ml_su_conf1", bytes_vec(su_conf1));
+  put("ml_su_start", bytes_vec(su_start));
+  put("ml_su_dl", bytes_vec(su_dl));
+  put("ml_dd_node", bytes_vec(dd_node));
+  put("ml_dd_t", bytes_vec(dd_t));
+  put("ml_pay", bytes_vec(pay));
+  put("ml_pay_n", bytes_vec(pay_n));
+  put("ml_pay_tag", bytes_vec(pay_tag));
+  return ok ? 0 : -1;
+}
+
 static PyObject *eng_span_export_phold(EngineObj *self, PyObject *args) {
   /* (I, T, R, S, C, P) capacity caps -> dict of column bytes, or None
    * when the sim is not phold-shaped or state exceeds the caps (the
    * caller falls back to the C++ span loop).  Read-only. */
   long long I, T, R, S, C, P;
-  if (!PyArg_ParseTuple(args, "LLLLLL", &I, &T, &R, &S, &C, &P))
+  /* memberlist caps: broadcast queue, relays, suspicions, dead
+   * members, payload slots per sender, parts per datagram */
+  long long BQ = 0, RL = 0, SU = 0, DD = 0, Q = 0, NP = 0;
+  if (!PyArg_ParseTuple(args, "LLLLLL|(LLLLLL)", &I, &T, &R, &S, &C, &P,
+                        &BQ, &RL, &SU, &DD, &Q, &NP))
     return nullptr;
   Engine *e = self->eng;
   Engine::PholdShape sh;
   /* None = structurally not a phold sim (permanent for this run);
    * int 1 = transiently beyond the caps (retry later / fall back). */
   if (!e->phold_shape(&sh)) Py_RETURN_NONE;
+  if (sh.family == 2 && Q <= 0) Py_RETURN_NONE;
   if ((long long)sh.n_peers_max > P) Py_RETURN_NONE;
   /* Pad peers to the tightest power of two, not the ceiling: the
    * column crosses the device link every span. */
@@ -6266,11 +7222,19 @@ static PyObject *eng_span_export_phold(EngineObj *self, PyObject *args) {
   auto pk_pad = [](Engine::PkCols &c, size_t upto) {
     while (c.src_host.size() < upto) c.push_empty();
   };
+  /* memberlist: a crashed member's host has no app and no socket,
+   * and no member has a seeder thread */
+  AppN no_app;
+  no_app.exited = true;
+  UdpSocketN no_sock(-1, 0, 0);
+  no_sock.status = S_CLOSED;
   for (size_t h = 0; h < H; h++) {
     HostPlane *hp = e->hosts[h].get();
-    AppN &m = e->apps[(size_t)sh.main_idx[h]];
-    AppN &s = e->apps[(size_t)sh.seed_idx[h]];
-    UdpSocketN *u = e->udp((uint32_t)m.sock);
+    AppN &m = sh.main_idx[h] >= 0 ? e->apps[(size_t)sh.main_idx[h]]
+                                  : no_app;
+    AppN &s = sh.seed_idx[h] >= 0 ? e->apps[(size_t)sh.seed_idx[h]]
+                                  : no_app;
+    UdpSocketN *u = m.sock >= 0 ? e->udp((uint32_t)m.sock) : &no_sock;
     if ((long long)u->recv_q.size() > R / 2 ||
         (long long)u->send_q[1].size() > S / 2 ||
         (long long)hp->codel.q.size() > C / 2 ||
@@ -6438,6 +7402,7 @@ static PyObject *eng_span_export_phold(EngineObj *self, PyObject *args) {
     put((p + "_sport").c_str(), bytes_vec(c.sport));
     put((p + "_dip").c_str(), bytes_vec(c.dip));
     put((p + "_dport").c_str(), bytes_vec(c.dport));
+    if (sh.family == 2) put((p + "_plen").c_str(), bytes_vec(c.plen));
   };
   put("rq_len", bytes_vec(rq_len));
   put_pk("rq", rq);
@@ -6524,6 +7489,14 @@ static PyObject *eng_span_export_phold(EngineObj *self, PyObject *args) {
   put("eth_precv", bytes_vec(eth_precv));
   put("eth_bsent", bytes_vec(eth_bsent));
   put("eth_brecv", bytes_vec(eth_brecv));
+  if (sh.family == 2 && ok) {
+    int r = ml_export(e, sh, d, BQ, RL, SU, DD, Q, NP);
+    if (r < 0) ok = false;
+    if (r > 0) {
+      Py_DECREF(d);
+      return PyLong_FromLong(1);  // over a memberlist cap
+    }
+  }
   if (!ok) {
     Py_DECREF(d);
     return nullptr;
@@ -6543,6 +7516,235 @@ static const T *col(PyObject *d, const char *k, size_t need,
     return nullptr;
   }
   return (const T *)PyBytes_AS_STRING(v);
+}
+
+/* A dict column of exactly `bytes` bytes (col<> without a type). */
+static const char *ml_cols(PyObject *d, const char *k, size_t bytes,
+                           bool *ok) {
+  PyObject *v = PyDict_GetItemString(d, k);
+  if (v == nullptr || !PyBytes_Check(v) ||
+      (size_t)PyBytes_GET_SIZE(v) != bytes) {
+    PyErr_Format(PyExc_ValueError, "span import: bad column %s", k);
+    *ok = false;
+    return nullptr;
+  }
+  return PyBytes_AS_STRING(v);
+}
+
+/* The payload store of a memberlist span's result (ml_import's
+ * columns ml_caps / ml_pay*), for rebuilding the datagrams in flight. */
+struct MlPayStore {
+  const int32_t *pay = nullptr, *n = nullptr;
+  const int64_t *tag = nullptr;
+  size_t Q = 0, NP = 0;
+};
+static bool ml_pay_store(PyObject *d, size_t H, MlPayStore *ps) {
+  PyObject *c = PyDict_GetItemString(d, "ml_caps");
+  if (c == nullptr || !PyBytes_Check(c) || PyBytes_GET_SIZE(c) != 48) {
+    PyErr_SetString(PyExc_ValueError, "span import: bad ml caps");
+    return false;
+  }
+  const int64_t *caps = (const int64_t *)PyBytes_AS_STRING(c);
+  ps->Q = (size_t)caps[4];
+  ps->NP = (size_t)caps[5];
+  bool ok = true;
+  ps->pay = (const int32_t *)ml_cols(d, "ml_pay", H * ps->Q * ps->NP * 16,
+                                     &ok);
+  ps->n = (const int32_t *)ml_cols(d, "ml_pay_n", H * ps->Q * 4, &ok);
+  ps->tag = (const int64_t *)ml_cols(d, "ml_pay_tag", H * ps->Q * 8, &ok);
+  return ok;
+}
+
+/* memberlist (family 2) half of the device-span import: every member's
+ * SWIM state back from the span's result (the inverse of ml_export). */
+static bool ml_import(Engine *e, const Engine::PholdShape &sh, PyObject *d) {
+  size_t H = e->hosts.size();
+  size_t N = (size_t)e->ml.N;
+  bool ok = true;
+  const int64_t *caps = col<int64_t>(d, "ml_caps", 6, &ok);
+  if (!ok) return false;
+  size_t BQ = (size_t)caps[0], RL = (size_t)caps[1], SU = (size_t)caps[2],
+         DD = (size_t)caps[3], Q = (size_t)caps[4], NP = (size_t)caps[5];
+  /* the payload store: span_import_phold rebuilt the datagrams in
+   * flight from it (ml_pay_store); read here for its schema check */
+  const int32_t *pay = col<int32_t>(d, "ml_pay", H * Q * NP * 4, &ok);
+  const int32_t *pay_n = col<int32_t>(d, "ml_pay_n", H * Q, &ok);
+  const int64_t *pay_tag = col<int64_t>(d, "ml_pay_tag", H * Q, &ok);
+  (void)pay;
+  (void)pay_n;
+  (void)pay_tag;
+  const int32_t *n = col<int32_t>(d, "ml_n", H, &ok);
+  const int32_t *score = col<int32_t>(d, "ml_score", H, &ok);
+  const int32_t *pidx = col<int32_t>(d, "ml_pidx", H, &ok);
+  const uint32_t *inc = col<uint32_t>(d, "ml_inc", H, &ok);
+  const uint32_t *seq = col<uint32_t>(d, "ml_seq", H, &ok);
+  const uint32_t *ctr_shuf = col<uint32_t>(d, "ml_ctr_shuf", H, &ok);
+  const uint32_t *ctr_pick = col<uint32_t>(d, "ml_ctr_pick", H, &ok);
+  const int64_t *probe_next = col<int64_t>(d, "ml_probe_next", H, &ok);
+  const int64_t *gossip_next = col<int64_t>(d, "ml_gossip_next", H, &ok);
+  const int64_t *armed = col<int64_t>(d, "ml_armed", H, &ok);
+  const uint8_t *tick = col<uint8_t>(d, "ml_tick", H, &ok);
+  const uint8_t *p_active = col<uint8_t>(d, "ml_p_active", H, &ok);
+  const int32_t *p_target = col<int32_t>(d, "ml_p_target", H, &ok);
+  const int32_t *p_exp = col<int32_t>(d, "ml_p_exp", H, &ok);
+  const int32_t *p_nacks = col<int32_t>(d, "ml_p_nacks", H, &ok);
+  const uint32_t *p_seq = col<uint32_t>(d, "ml_p_seq", H, &ok);
+  const uint32_t *p_inc = col<uint32_t>(d, "ml_p_inc", H, &ok);
+  const int64_t *p_to = col<int64_t>(d, "ml_p_to", H, &ok);
+  const int64_t *p_dl = col<int64_t>(d, "ml_p_dl", H, &ok);
+  const int64_t *bq_id = col<int64_t>(d, "ml_bq_id", H, &ok);
+  const int64_t *cnt = col<int64_t>(d, "ml_cnt", H * MLC_N, &ok);
+  const int32_t *bq_node = col<int32_t>(d, "ml_bq_node", H * BQ, &ok);
+  const int32_t *bq_from = col<int32_t>(d, "ml_bq_from", H * BQ, &ok);
+  const int32_t *bq_tx = col<int32_t>(d, "ml_bq_tx", H * BQ, &ok);
+  const int32_t *bq_len = col<int32_t>(d, "ml_bq_len", H * BQ, &ok);
+  const uint8_t *bq_kind = col<uint8_t>(d, "ml_bq_kind", H * BQ, &ok);
+  const uint32_t *bq_inc = col<uint32_t>(d, "ml_bq_inc", H * BQ, &ok);
+  const int64_t *bq_bid = col<int64_t>(d, "ml_bq_bid", H * BQ, &ok);
+  const int32_t *rl_valid = col<int32_t>(d, "ml_rl_valid", H * RL, &ok);
+  const uint32_t *rl_seq = col<uint32_t>(d, "ml_rl_seq", H * RL, &ok);
+  const uint32_t *rl_oseq = col<uint32_t>(d, "ml_rl_oseq", H * RL, &ok);
+  const int32_t *rl_from = col<int32_t>(d, "ml_rl_from", H * RL, &ok);
+  const uint8_t *rl_nack = col<uint8_t>(d, "ml_rl_nack", H * RL, &ok);
+  const int64_t *rl_dl = col<int64_t>(d, "ml_rl_dl", H * RL, &ok);
+  const int32_t *su_node = col<int32_t>(d, "ml_su_node", H * SU, &ok);
+  const int32_t *su_from = col<int32_t>(d, "ml_su_from", H * SU, &ok);
+  const int32_t *su_n0 = col<int32_t>(d, "ml_su_n0", H * SU, &ok);
+  const int32_t *su_k = col<int32_t>(d, "ml_su_k", H * SU, &ok);
+  const int32_t *su_c = col<int32_t>(d, "ml_su_c", H * SU, &ok);
+  const int32_t *su_conf0 = col<int32_t>(d, "ml_su_conf0", H * SU, &ok);
+  const int32_t *su_conf1 = col<int32_t>(d, "ml_su_conf1", H * SU, &ok);
+  const int64_t *su_start = col<int64_t>(d, "ml_su_start", H * SU, &ok);
+  const int64_t *su_dl = col<int64_t>(d, "ml_su_dl", H * SU, &ok);
+  const int32_t *dd_node = col<int32_t>(d, "ml_dd_node", H * DD, &ok);
+  const int64_t *dd_t = col<int64_t>(d, "ml_dd_t", H * DD, &ok);
+  if (!ok) return false;
+  for (size_t h = 0; h < H; h++) {
+    if (sh.main_idx[h] < 0) continue;
+    MlState &m = *e->mls[(size_t)e->apps[(size_t)sh.main_idx[h]].ml];
+    m.n = n[h];
+    m.score = score[h];
+    m.pidx = pidx[h];
+    m.inc = inc[h];
+    m.seq = seq[h];
+    m.ctr_shuf = ctr_shuf[h];
+    m.ctr_pick = ctr_pick[h];
+    m.probe_next = probe_next[h];
+    m.gossip_next = gossip_next[h];
+    m.armed = armed[h];
+    m.tick_pending = tick[h];
+    m.p_active = p_active[h];
+    m.p_target = p_target[h];
+    m.p_exp = p_exp[h];
+    m.p_nacks = p_nacks[h];
+    m.p_seq = p_seq[h];
+    m.p_inc = p_inc[h];
+    m.p_to = p_to[h];
+    m.p_dl = p_dl[h];
+    m.bq_id = bq_id[h];
+    for (int j = 0; j < MLC_N; j++) m.cnt[j] = cnt[h * MLC_N + j];
+    /* queue entries in the order they were queued (their ids) */
+    m.bq.clear();
+    for (size_t j = 0; j < BQ; j++) {
+      size_t k = h * BQ + j;
+      if (bq_node[k] < 0) continue;
+      m.bq.push_back({bq_node[k], bq_from[k], bq_tx[k], bq_len[k],
+                      bq_kind[k], bq_inc[k], bq_bid[k]});
+    }
+    std::sort(m.bq.begin(), m.bq.end(),
+              [](const MlBcast &a, const MlBcast &b) { return a.id < b.id; });
+    m.relays.clear();
+    for (size_t j = 0; j < RL; j++) {
+      size_t k = h * RL + j;
+      if (rl_valid[k])
+        m.relays.push_back({rl_seq[k], rl_oseq[k], rl_from[k], rl_nack[k],
+                            rl_dl[k]});
+    }
+    m.susp.clear();
+    for (size_t j = 0; j < SU; j++) {
+      size_t k = h * SU + j;
+      if (su_node[k] < 0) continue;
+      MlSusp x{};
+      x.node = su_node[k];
+      x.from = su_from[k];
+      x.n0 = su_n0[k];
+      x.k = su_k[k];
+      x.c = su_c[k];
+      x.conf[0] = su_conf0[k];
+      x.conf[1] = su_conf1[k];
+      x.start = su_start[k];
+      x.dl = su_dl[k];
+      m.susp.push_back(x);
+    }
+    m.dead.clear();
+    for (size_t j = 0; j < DD; j++) {
+      size_t k = h * DD + j;
+      if (dd_node[k] >= 0) m.dead.push_back({dd_node[k], dd_t[k]});
+    }
+  }
+  (void)N;
+  return true;
+}
+
+/* The memberlist members' dense views and probe orders, (H, N) each
+ * (state u8, incarnation u32, order u16; a host with no member is all
+ * reaped): the device-span export's largest leg, apart so its wall
+ * time books on its own (the `view-export` phase).  None when the sim
+ * runs no member. */
+static PyObject *eng_span_export_ml_view(EngineObj *self, PyObject *) {
+  Engine *e = self->eng;
+  size_t H = e->hosts.size(), N = (size_t)e->ml.N;
+  if (N == 0) Py_RETURN_NONE;
+  std::vector<uint8_t> vstate(H * N, ML_REAPED);
+  std::vector<uint32_t> vinc(H * N, 0);
+  std::vector<uint16_t> order(H * N, 0);
+  for (size_t i = 0; i < e->apps.size(); i++) {
+    const AppN &a = e->apps[i];
+    if (a.kind != APP_MEMBERLIST || a.ml < 0 || a.exited) continue;
+    const MlState &m = *e->mls[(size_t)a.ml];
+    size_t h = (size_t)a.hid;
+    memcpy(&vstate[h * N], m.vstate.data(), N);
+    memcpy(&vinc[h * N], m.vinc.data(), N * 4);
+    memcpy(&order[h * N], m.order.data(), N * 2);
+  }
+  PyObject *d = PyDict_New();
+  if (d == nullptr) return nullptr;
+  bool ok = true;
+  auto put = [&](const char *k, PyObject *v) {
+    if (dict_set(d, k, v) < 0) ok = false;
+  };
+  put("ml_vstate", bytes_vec(vstate));
+  put("ml_vinc", bytes_vec(vinc));
+  put("ml_order", bytes_vec(order));
+  if (!ok) {
+    Py_DECREF(d);
+    return nullptr;
+  }
+  return d;
+}
+
+/* span_export_ml_view's inverse, after a clean span (`view-import`). */
+static PyObject *eng_span_import_ml_view(EngineObj *self, PyObject *args) {
+  self->eng->state_epoch++;
+  PyObject *d;
+  if (!PyArg_ParseTuple(args, "O", &d)) return nullptr;
+  Engine *e = self->eng;
+  size_t H = e->hosts.size(), N = (size_t)e->ml.N;
+  bool ok = true;
+  const uint8_t *vstate = col<uint8_t>(d, "ml_vstate", H * N, &ok);
+  const uint32_t *vinc = col<uint32_t>(d, "ml_vinc", H * N, &ok);
+  const uint16_t *order = col<uint16_t>(d, "ml_order", H * N, &ok);
+  if (!ok) return nullptr;
+  for (size_t i = 0; i < e->apps.size(); i++) {
+    const AppN &a = e->apps[i];
+    if (a.kind != APP_MEMBERLIST || a.ml < 0 || a.exited) continue;
+    MlState &m = *e->mls[(size_t)a.ml];
+    size_t h = (size_t)a.hid;
+    memcpy(m.vstate.data(), vstate + h * N, N);
+    memcpy(m.vinc.data(), vinc + h * N, N * 4);
+    memcpy(m.order.data(), order + h * N, N * 2);
+  }
+  Py_RETURN_NONE;
 }
 
 static PyObject *eng_span_import_phold(EngineObj *self, PyObject *args) {
@@ -6582,6 +7784,7 @@ static PyObject *eng_span_import_phold(EngineObj *self, PyObject *args) {
     const int64_t *pseq;
     const uint32_t *sip, *dip;
     const int32_t *sport, *dport;
+    const int32_t *plen;  // memberlist: payload bytes per packet
   };
   auto get_pk = [&](const char *prefix, size_t n) {
     std::string p(prefix);
@@ -6592,6 +7795,9 @@ static PyObject *eng_span_import_phold(EngineObj *self, PyObject *args) {
     c.sport = col<int32_t>(d, (p + "_sport").c_str(), n, &ok);
     c.dip = col<uint32_t>(d, (p + "_dip").c_str(), n, &ok);
     c.dport = col<int32_t>(d, (p + "_dport").c_str(), n, &ok);
+    c.plen = nullptr;
+    if (sh.family == 2)
+      c.plen = col<int32_t>(d, (p + "_plen").c_str(), n, &ok);
     return c;
   };
   Pk rq = get_pk("rq", H * R), sq = get_pk("sq", H * S),
@@ -6679,6 +7885,13 @@ static PyObject *eng_span_import_phold(EngineObj *self, PyObject *args) {
   const int64_t *eth_precv = col<int64_t>(d, "eth_precv", H, &ok);
   const int64_t *eth_bsent = col<int64_t>(d, "eth_bsent", H, &ok);
   const int64_t *eth_brecv = col<int64_t>(d, "eth_brecv", H, &ok);
+  /* memberlist: the payload store the device kept for every datagram
+   * in flight, at (sender, packet number % Q) */
+  MlPayStore mps;
+  if (sh.family == 2 && !ml_pay_store(d, H, &mps)) return nullptr;
+  const int32_t *ml_pay = mps.pay, *ml_pay_n = mps.n;
+  const int64_t *ml_pay_tag = mps.tag;
+  size_t mlQ = mps.Q, mlNP = mps.NP;
   if (!ok) return nullptr;
 
   /* Lengths are read from an arbitrary Python dict: validate against
@@ -6694,11 +7907,18 @@ static PyObject *eng_span_import_phold(EngineObj *self, PyObject *args) {
     }
   }
 
+  AppN no_app;  // see span_export_phold
+  no_app.exited = true;
+  UdpSocketN no_sock(-1, 0, 0);
+  no_sock.status = S_CLOSED;
+  bool ml_bad = false;
   for (size_t h = 0; h < H; h++) {
     HostPlane *hp = e->hosts[h].get();
-    AppN &m = e->apps[(size_t)sh.main_idx[h]];
-    AppN &s = e->apps[(size_t)sh.seed_idx[h]];
-    UdpSocketN *u = e->udp((uint32_t)m.sock);
+    AppN &m = sh.main_idx[h] >= 0 ? e->apps[(size_t)sh.main_idx[h]]
+                                  : no_app;
+    AppN &s = sh.seed_idx[h] >= 0 ? e->apps[(size_t)sh.seed_idx[h]]
+                                  : no_app;
+    UdpSocketN *u = m.sock >= 0 ? e->udp((uint32_t)m.sock) : &no_sock;
     bool was_queued = u->queued[1];
     bool was_closed = (u->status & S_CLOSED) != 0;
     /* free live engine packets; the device result replaces them */
@@ -6726,8 +7946,29 @@ static PyObject *eng_span_import_phold(EngineObj *self, PyObject *args) {
     u->recv_bytes = recv_bytes[h];
     u->send_bytes = send_bytes[h];
     auto mk = [&](const Pk &c, size_t j) {
-      return e->pk_alloc(c.srchost[j], c.pseq[j], c.sip[j], c.sport[j],
-                         c.dip[j], c.dport[j], sh.family, sh.pay_size);
+      uint64_t id = e->pk_alloc(c.srchost[j], c.pseq[j], c.sip[j],
+                                c.sport[j], c.dip[j], c.dport[j],
+                                sh.family, sh.pay_size);
+      if (sh.family == 2) {
+        size_t slot = (size_t)c.srchost[j] * mlQ +
+                      (size_t)((uint64_t)c.pseq[j] % mlQ);
+        if (c.srchost[j] < 0 || (size_t)c.srchost[j] >= H ||
+            ml_pay_tag[slot] != c.pseq[j] ||
+            ml_pay_n[slot] < 0 || (size_t)ml_pay_n[slot] > mlNP) {
+          ml_bad = true;
+          return id;
+        }
+        std::vector<MlPart> parts((size_t)ml_pay_n[slot]);
+        for (size_t q = 0; q < parts.size(); q++) {
+          const int32_t *w = &ml_pay[(slot * mlNP + q) * 4];
+          parts[q].type = (uint8_t)w[0];
+          parts[q].a = (uint32_t)w[1];
+          parts[q].b = (uint32_t)w[2];
+          parts[q].c = (uint32_t)w[3];
+        }
+        ml_encode(parts, c.plen[j], &e->store.get(id)->payload);
+      }
+      return id;
     };
     for (int32_t j = 0; j < rq_len[h]; j++)
       u->recv_q.push_back(mk(rq, h * (size_t)R + (size_t)j));
@@ -6884,6 +8125,13 @@ static PyObject *eng_span_import_phold(EngineObj *self, PyObject *args) {
       e->nt[h] = best;
     }
   }
+
+  if (ml_bad) {
+    PyErr_SetString(PyExc_ValueError,
+                    "span import: a datagram in flight has no payload");
+    return nullptr;
+  }
+  if (sh.family == 2 && !ml_import(e, sh, d)) return nullptr;
 
   /* trace records: (t i64, kind u8, srchost i32, pseq i64, sip u32,
    * sport i32, dip u32, dport i32, size i64, reason u8, owner i32)
@@ -8880,6 +10128,25 @@ static PyObject *eng_counters(EngineObj *self, PyObject *args) {
                        (long long)hp->events_run);
 }
 
+/* memberlist: the host's member's protocol counters (MLC_NAMES order),
+ * or None when the host runs no member */
+static PyObject *eng_ml_counters(EngineObj *self, PyObject *args) {
+  int hid;
+  if (!PyArg_ParseTuple(args, "i", &hid)) return nullptr;
+  Engine *e = self->eng;
+  for (size_t i = 0; i < e->apps.size(); i++) {
+    const AppN &a = e->apps[i];
+    if (a.kind != APP_MEMBERLIST || a.hid != hid || a.ml < 0) continue;
+    const MlState &m = *e->mls[(size_t)a.ml];
+    PyObject *t = PyTuple_New(MLC_N);
+    if (t == nullptr) return nullptr;
+    for (int j = 0; j < MLC_N; j++)
+      PyTuple_SET_ITEM(t, j, PyLong_FromLongLong(m.cnt[j]));
+    return t;
+  }
+  Py_RETURN_NONE;
+}
+
 static PyObject *eng_mt_stats(EngineObj *self, PyObject *) {
   return Py_BuildValue("LL", (long long)self->eng->mt_batches,
                        (long long)self->eng->mt_hosts_run);
@@ -9420,6 +10687,11 @@ static PyMethodDef eng_methods[] = {
     {"packet_fields", (PyCFunction)eng_packet_fields, METH_VARARGS, nullptr},
     {"intern_packet", (PyCFunction)eng_intern_packet, METH_VARARGS, nullptr},
     {"trace_entries", (PyCFunction)eng_trace_entries, METH_VARARGS, nullptr},
+    {"ml_counters", (PyCFunction)eng_ml_counters, METH_VARARGS, nullptr},
+    {"span_export_ml_view", (PyCFunction)eng_span_export_ml_view,
+     METH_NOARGS, nullptr},
+    {"span_import_ml_view", (PyCFunction)eng_span_import_ml_view,
+     METH_VARARGS, nullptr},
     {"counters", (PyCFunction)eng_counters, METH_VARARGS, nullptr},
     {"state_epoch", (PyCFunction)eng_state_epoch, METH_NOARGS, nullptr},
     {"set_flight", (PyCFunction)eng_set_flight, METH_VARARGS, nullptr},

@@ -82,7 +82,7 @@ MUTATORS = frozenset({
     "advance_clocks", "fire", "deliver", "finish_round",
     "export_round", "scatter_round", "push_inbox", "take_outgoing",
     # device-span import (overwrites host state wholesale)
-    "span_import_phold", "span_import_tcp",
+    "span_import_phold", "span_import_tcp", "span_import_ml_view",
     # snapshot import (rebuilds host state wholesale)
     "plane_import", "host_import",
     # sequence allocators (consume deterministic id streams)
@@ -105,7 +105,8 @@ OBSERVERS = frozenset({
     "trace_entries", "set_flight", "set_netstat", "set_fabric",
     "set_devcap_probe", "netstat_sample", "fabric_sample",
     # counters / probes / shape reads
-    "counters", "mt_stats", "devcap_counters", "fabric_counters",
+    "counters", "ml_counters", "mt_stats", "devcap_counters",
+    "fabric_counters",
     "drop_causes", "mark_causes", "netstat_totals", "fct_flows",
     "round_size", "peek_next", "peek_deadline", "packet_fields",
     "tcp_info", "sock_addr", "sock_inq", "sock_status",
@@ -115,7 +116,7 @@ OBSERVERS = frozenset({
     "plane_export", "state_epoch",
     # device-span export is read-only (the engine stays authoritative;
     # an aborted span simply never imports)
-    "span_export_phold", "span_export_tcp",
+    "span_export_phold", "span_export_tcp", "span_export_ml_view",
 })
 
 ENTRY_EFFECTS = {name: "mutator" for name in MUTATORS}

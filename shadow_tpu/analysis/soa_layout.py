@@ -16,7 +16,11 @@ abort or a byte-mismatch at runtime.
 To register a new device-span family: add a row to FAMILIES naming the
 C++ export/import functions and the Python codec module; the codec
 must expose `_to_arrays` / `_from_arrays` methods using the
-`np.frombuffer(d[key], dtype)` / `out[key] = ...` idioms.
+`np.frombuffer(d[key], dtype)` / `out[key] = ...` idioms (a row may
+name other methods: `to_method` / `from_method`).  A module that
+serves several rows keeps one set of RESIDENT_* tables: its rows'
+state columns are classified together, by the row with `residency`
+(the others say `"residency": False`).
 """
 
 from __future__ import annotations
@@ -45,6 +49,27 @@ FAMILIES = [
         "codec": "shadow_tpu/ops/tcp_span.py",
         "min_columns": 120,
     },
+    # memberlist (family 2 of the phold rows): the members' SWIM state
+    # rides span_export_phold through ml_export / ml_import, their
+    # dense views through calls of their own
+    {
+        "name": "memberlist",
+        "export_fn": "ml_export",
+        "import_fn": "ml_import",
+        "codec": "shadow_tpu/ops/memberlist_span.py",
+        "min_columns": 50,
+        "state_methods": ("_to_arrays", "_view_to_arrays"),
+    },
+    {
+        "name": "memberlist-view",
+        "export_fn": "eng_span_export_ml_view",
+        "import_fn": "eng_span_import_ml_view",
+        "codec": "shadow_tpu/ops/memberlist_span.py",
+        "to_method": "_view_to_arrays",
+        "from_method": "_view_from_arrays",
+        "min_columns": 3,
+        "residency": False,
+    },
 ]
 
 
@@ -67,8 +92,12 @@ def check(repo_root: str, cpp_text: str | None = None) -> list:
             violations.append(Violation(
                 "soa-layout", CPP, f"[{name}] {exc.args[0]}"))
             continue
-        consumed, unres_c = py_extract.extract_consumed_schema(codec_path)
-        produced, unres_p = py_extract.extract_produced_keys(codec_path)
+        to_m = fam.get("to_method", "_to_arrays")
+        from_m = fam.get("from_method", "_from_arrays")
+        consumed, unres_c = py_extract.extract_consumed_schema(
+            codec_path, to_m)
+        produced, unres_p = py_extract.extract_produced_keys(
+            codec_path, from_m)
 
         if len(exported) < fam["min_columns"]:
             violations.append(Violation(
@@ -129,7 +158,14 @@ def check(repo_root: str, cpp_text: str | None = None) -> list:
         # RESIDENT_* tables — a column added to the export without a
         # classification entry would otherwise be reused across
         # device-resident spans with unreviewed dirtiness semantics.
-        state_keys, unres_s = py_extract.extract_state_keys(codec_path)
+        if not fam.get("residency", True):
+            continue
+        state_keys, unres_s = set(), []
+        for method in fam.get("state_methods", ("_to_arrays",)):
+            keys, unres = py_extract.extract_state_keys(codec_path,
+                                                        method)
+            state_keys |= keys
+            unres_s += unres
         for line, what in unres_s:
             violations.append(Violation(
                 "soa-layout", codec,

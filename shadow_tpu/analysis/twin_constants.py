@@ -35,6 +35,7 @@ _RNG = "shadow_tpu/core/rng.py"
 _PLANE = "shadow_tpu/native/plane.py"
 _TREV = "shadow_tpu/trace/events.py"
 _CKPT = "shadow_tpu/ckpt/format.py"
+_MLS = "shadow_tpu/ops/memberlist_span.py"
 
 # cpp_name -> [(python module, python name)]
 CONTRACTS = [
@@ -277,6 +278,48 @@ CONTRACTS = [
                    (_PHLD, "KS_TIMERS")]),
     ("KS_EXCHANGE", [(_TREV, "KS_EXCHANGE"), (_TCPS, "KS_EXCHANGE"),
                      (_PHLD, "KS_EXCHANGE")]),
+    ("KS_PROBE", [(_TREV, "KS_PROBE"), (_PHLD, "KS_PROBE")]),
+    ("KS_GOSSIP", [(_TREV, "KS_GOSSIP"), (_PHLD, "KS_GOSSIP")]),
+    ("KS_MERGE", [(_TREV, "KS_MERGE"), (_PHLD, "KS_MERGE")]),
+    # memberlist (SWIM + Lifeguard): the engine app APP_MEMBERLIST and
+    # its device twin, span family 2 (ops/memberlist_span.py)
+    ("ML_ALIVE", [(_MLS, "ML_ALIVE")]),
+    ("ML_SUSPECT", [(_MLS, "ML_SUSPECT")]),
+    ("ML_DEAD", [(_MLS, "ML_DEAD")]),
+    ("ML_REAPED", [(_MLS, "ML_REAPED")]),
+    ("MLM_PING", [(_MLS, "MLM_PING")]),
+    ("MLM_PINGREQ", [(_MLS, "MLM_PINGREQ")]),
+    ("MLM_ACK", [(_MLS, "MLM_ACK")]),
+    ("MLM_NACK", [(_MLS, "MLM_NACK")]),
+    ("MLM_SUSPECT", [(_MLS, "MLM_SUSPECT")]),
+    ("MLM_DEAD", [(_MLS, "MLM_DEAD")]),
+    ("MLM_ALIVE", [(_MLS, "MLM_ALIVE")]),
+    ("ML_NEVER", [(_MLS, "ML_NEVER")]),
+    ("ML_PROBE_IV", [(_MLS, "ML_PROBE_IV")]),
+    ("ML_PROBE_TO", [(_MLS, "ML_PROBE_TO")]),
+    ("ML_GOSSIP_IV", [(_MLS, "ML_GOSSIP_IV")]),
+    ("ML_G2DEAD", [(_MLS, "ML_G2DEAD")]),
+    ("ML_INDIRECT", [(_MLS, "ML_INDIRECT")]),
+    ("ML_GOSSIP_NODES", [(_MLS, "ML_GOSSIP_NODES")]),
+    ("ML_RETX_MULT", [(_MLS, "ML_RETX_MULT")]),
+    ("ML_SUSP_MULT", [(_MLS, "ML_SUSP_MULT")]),
+    ("ML_SUSP_MAX_MULT", [(_MLS, "ML_SUSP_MAX_MULT")]),
+    ("ML_SUSP_K", [(_MLS, "ML_SUSP_K")]),
+    ("ML_AWARE_MAX", [(_MLS, "ML_AWARE_MAX")]),
+    ("ML_UDP_BUF", [(_MLS, "ML_UDP_BUF")]),
+    ("ML_DRAW_STAGGER", [(_MLS, "ML_DRAW_STAGGER")]),
+    ("ML_DRAW_SHUFFLE", [(_MLS, "ML_DRAW_SHUFFLE")]),
+    ("ML_DRAW_PICK", [(_MLS, "ML_DRAW_PICK")]),
+    ("MLC_PING", [(_MLS, "MLC_PING")]),
+    ("MLC_ACK", [(_MLS, "MLC_ACK")]),
+    ("MLC_PINGREQ", [(_MLS, "MLC_PINGREQ")]),
+    ("MLC_NACK", [(_MLS, "MLC_NACK")]),
+    ("MLC_SUSPECT", [(_MLS, "MLC_SUSPECT")]),
+    ("MLC_DEAD", [(_MLS, "MLC_DEAD")]),
+    ("MLC_ALIVE", [(_MLS, "MLC_ALIVE")]),
+    ("MLC_UPD_SENT", [(_MLS, "MLC_UPD_SENT")]),
+    ("MLC_UPD_DROPPED", [(_MLS, "MLC_UPD_DROPPED")]),
+    ("MLC_N", [(_MLS, "MLC_N")]),
     ("KS_N", [(_TREV, "KS_N"), (_TCPS, "KS_N"), (_PHLD, "KS_N")]),
     ("KS_REC_BYTES", [(_TREV, "KS_REC_BYTES")]),
     # Checkpoint plane-blob framing (shadow_tpu/ckpt/format.py is the
@@ -296,7 +339,8 @@ CONTRACTS = [
 # flight-record layout or the drop-cause table without updating
 # trace/events.py fails closed.
 TRACE_ENUM_PREFIXES = ("FR_", "EL_", "TEL_", "FB_", "FCT_", "CK_",
-                       "MARK_", "DCTCP_", "ECN_", "CC_", "KS_")
+                       "MARK_", "DCTCP_", "ECN_", "CC_", "KS_", "ML_",
+                       "MLM_", "MLC_")
 
 # Shim-side contracts (native/shim.c — the syscall observatory's SC_*
 # disposition enum, its record-size pin, and the IPC-layout offset of

@@ -23,6 +23,7 @@ KIND_UDP_MESH = 5
 KIND_PHOLD = 7
 KIND_UDP_ECHO = 9
 KIND_UDP_PING = 10
+KIND_MEMBERLIST = 11
 
 
 class _EngineFdView:
@@ -250,7 +251,42 @@ def engine_app_args(pcfg, host, dns):
             return None
         return (KIND_PHOLD, int(args[0]), int(args[1]), int(args[2]),
                 int(args[3]), 0, peers)
+    if pcfg.path == "memberlist":
+        # memberlist <port> <member> <name pattern> <count> <key>
+        # [<placement>]: the pool's members sit on the pattern's hosts
+        # (apps.memberlist_pool), resolved once into one shared address
+        # buffer.
+        if len(args) not in (5, 6):
+            return None
+        count = int(args[3])
+        if not 1 <= count <= 65535 or not 0 <= int(args[1]) < count:
+            return None
+        place = int(args[5]) if len(args) > 5 else None
+        ips = _pool_ips(dns, args[2], count, place)
+        if ips is None:
+            return None
+        return (KIND_MEMBERLIST, int(args[0]), int(args[1]), count,
+                int(args[4]), 0, ips)
     return None
+
+
+_POOLS: dict = {}
+
+
+def _pool_ips(dns, pattern: str, count: int, place):
+    """The u32 address buffer of the pool's members in member order,
+    resolved once per name service (every member passes the same
+    buffer; the engine keeps one table)."""
+    from shadow_tpu.host.apps import memberlist_pool
+    key = (id(dns), pattern, count, place)
+    hit = _POOLS.get(key)
+    if hit is not None and hit[0] is dns:
+        return hit[1]
+    buf = _pack_peers(dns, memberlist_pool(pattern, count, place))
+    if buf is not None:
+        _POOLS.clear()
+        _POOLS[key] = (dns, buf)
+    return buf
 
 
 def _pack_peers(dns, names):

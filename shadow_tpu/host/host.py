@@ -579,6 +579,13 @@ class Host:
         for i in range(MARK_N):
             self.mark_causes[i] += marks[i] - prev[i]
         self._native_marks_merged = tuple(marks)
+        # A memberlist member's protocol counters (the engine's, or the
+        # device span's after its import): absolute, engine-owned.
+        ml = self.plane.engine.ml_counters(self.id)
+        if ml is not None:
+            from shadow_tpu.ops.memberlist_span import MLC_NAMES
+            for name, v in zip(MLC_NAMES, ml):
+                self.counters[f"memberlist_{name}"] = v
         # Engine-app syscalls (counted C++-side at the exact points the
         # Python dispatch would) fold into the same histograms.
         app_sys = self.plane.engine.app_syscalls(self.id)

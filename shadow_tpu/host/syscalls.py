@@ -164,14 +164,20 @@ class SyscallHandler:
             return _block(SyscallCondition(file=sock, mask=S_WRITABLE))
 
     def sys_recvfrom(self, host, process, thread, restarted, fd,
-                     bufsize=65536):
+                     bufsize=65536, timeout_at=None):
+        """With `timeout_at` (absolute sim ns, like SO_RCVTIMEO) the
+        call waits for a datagram until then, whatever the socket's
+        blocking mode, and fails with EAGAIN once it passes; one not
+        after now makes the call a poll."""
         sock = process.fds.get(fd)
         try:
             return _done(sock.recvfrom(host, bufsize))
         except BlockingIOError:
-            if sock.nonblocking:
+            if (sock.nonblocking if timeout_at is None
+                    else timeout_at <= host.now()):
                 return _error(errno.EWOULDBLOCK, "no data")
-            return _block(SyscallCondition(file=sock, mask=S_READABLE))
+            return _block(SyscallCondition(file=sock, mask=S_READABLE,
+                                           timeout_at=timeout_at))
 
     def sys_send(self, host, process, thread, restarted, fd, data):
         return self.sys_sendto(host, process, thread, restarted, fd, data,

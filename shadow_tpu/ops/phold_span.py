@@ -37,6 +37,7 @@ import numpy as np
 
 from shadow_tpu.core.rng import STREAM_PACKET_LOSS, mix_key, threefry2x32_jax
 from shadow_tpu.core.simtime import TIME_NEVER
+from shadow_tpu.ops import memberlist_span as mls
 from shadow_tpu.ops.span_mesh import SpanMeshMixin, scatter_set
 from shadow_tpu.trace.events import KS_NAMES
 from shadow_tpu.trace.recorder import Span
@@ -118,7 +119,10 @@ KS_INET_OUT = 8
 KS_ARM = 9
 KS_TIMERS = 10
 KS_EXCHANGE = 11
-KS_N = 12
+KS_PROBE = 12
+KS_GOSSIP = 13
+KS_MERGE = 14
+KS_N = 15
 
 PK_KEYS = ("srchost", "pseq", "sip", "sport", "dip", "dport")
 
@@ -185,7 +189,7 @@ RESIDENT_CARRIED = frozenset(
      "status", "th_kind", "th_seq", "th_tgt", "th_time",
      "th_valid", "h_fault"}
     | {f"{p}_{kk}" for p in ('rq', 'sq', 'cq', 'ib', 'r1_pk', 'r2_pk')
-       for kk in PK_KEYS})
+       for kk in PK_KEYS + ("plen",)})
 
 
 class PholdSpanRunner(SpanMeshMixin):
@@ -202,6 +206,12 @@ class PholdSpanRunner(SpanMeshMixin):
     CAP_C = 2048  # CoDel ring (covers the engine's 1000-entry hard limit)
     CAP_P = 4096  # peers
     MAX_ROUNDS = 256
+    # memberlist (family 2) per-member tables: broadcast queue,
+    # relayed probes, suspicion timers, dead members; payload slots
+    # per sender and parts per datagram (queue + the message + the
+    # buddy's accusation).  Export refuses state beyond them; the
+    # device aborts transactionally on overflow.
+    ML_CAPS = (16, 4, 8, 8, 16, 18)
     # Fabric observatory: per-round queue-sample rows buffered on
     # device; spans clamp to FAB_ROWS rounds while the channel
     # records so the (FAB_ROWS, H) buffers can never overflow.
@@ -240,7 +250,8 @@ class PholdSpanRunner(SpanMeshMixin):
         # XLA inserts the cross-shard collectives for the inbox
         # scatter.  Requires H % mesh size == 0.
         self.mesh = None
-        self.family = 0      # 0 phold, 1 udp-mesh (set from export)
+        self.family = 0      # 0 phold, 1 udp-mesh, 2 memberlist (export)
+        self._ml_n = 0       # memberlist pool size (set from export)
         self._pay = 5        # uniform payload bytes (set from export)
         # Fused micro-op dispatch (default): ops chain within one
         # while-iteration.  False rebuilds the one-micro-op-per-
@@ -286,6 +297,8 @@ class PholdSpanRunner(SpanMeshMixin):
             return a.copy()
 
         st = {}
+        self.family = int(np.frombuffer(d["family"], np.uint8)[0])
+        var = self.family == 2  # memberlist: per-packet sizes
         for k in ("now", "event_seq", "packet_seq", "recv_bytes",
                   "recv_max", "send_bytes", "send_max", "codel_bytes",
                   "codel_dropped", "m_waitseq", "m_gotn", "m_mean",
@@ -314,7 +327,6 @@ class PholdSpanRunner(SpanMeshMixin):
         st["out_first"] = np.zeros(H, np.int32)
         st["cd_chain"] = np.zeros(H, np.int32)
         st["cd_sniff"] = np.zeros(H, np.int32)
-        self.family = int(np.frombuffer(d["family"], np.uint8)[0])
         self._pay = int(np.frombuffer(d["pay_size"], np.int64)[0])
         # codel AQM bookkeeping rides along untouched; the device only
         # runs while the queue is quiescent (abort otherwise).
@@ -335,6 +347,8 @@ class PholdSpanRunner(SpanMeshMixin):
                            ("sip", np.uint32), ("sport", np.int32),
                            ("dip", np.uint32), ("dport", np.int32)):
                 st[f"{pfx}_{kk}"] = f(f"{pfx}_{kk}", dt, (H, cap))
+            if var:
+                st[f"{pfx}_plen"] = f(f"{pfx}_plen", np.int32, (H, cap))
             st[f"{pfx}_len"] = f(f"{pfx}_len", np.int32)
         st["cq_enq"] = f("cq_enq", np.int64, (H, C))
         st["ib_time"] = f("ib_time", np.int64, (H, I))
@@ -360,6 +374,8 @@ class PholdSpanRunner(SpanMeshMixin):
                            ("sip", np.uint32), ("sport", np.int32),
                            ("dip", np.uint32), ("dport", np.int32)):
                 st[f"r{r}_pk_{kk}"] = f(f"r{r}_pk_{kk}", dt)
+            if var:
+                st[f"r{r}_pk_plen"] = f(f"r{r}_pk_plen", np.int32)
         for k in ("rq_pos", "sq_pos", "cq_pos", "ib_pos"):
             st[k] = np.zeros(H, np.int32)
         st["cont"] = np.zeros(H, np.int32)
@@ -369,6 +385,9 @@ class PholdSpanRunner(SpanMeshMixin):
         # padded-slot invariants the sort/argmin tricks rely on
         st["ib_time"][np.arange(I)[None, :] >= st["ib_len"][:, None]] \
             = I64_MAX
+        if var:
+            st.update(mls.MlCodec(H, self.ML_CAPS)._to_arrays(d))
+            st.update(mls.ml_derived(np, H, self.ML_CAPS))
         return st
 
     def _from_arrays(self, st: dict) -> dict:
@@ -380,6 +399,8 @@ class PholdSpanRunner(SpanMeshMixin):
         def npv(k):
             return np.asarray(st[k])
 
+        var = self.family == 2  # memberlist: per-packet sizes
+
         def ring(pfx, cap, pos_k, len_k, modulo, extra=()):
             pos = npv(pos_k).astype(np.int64)
             ln = npv(len_k).astype(np.int64)
@@ -389,6 +410,9 @@ class PholdSpanRunner(SpanMeshMixin):
             for kk in PK_KEYS:
                 a = np.take_along_axis(npv(f"{pfx}_{kk}"), idx, axis=1)
                 out[f"{pfx}_{kk}"] = np.ascontiguousarray(a).tobytes()
+            if var:
+                a = np.take_along_axis(npv(f"{pfx}_plen"), idx, axis=1)
+                out[f"{pfx}_plen"] = np.ascontiguousarray(a).tobytes()
             for kk in extra:
                 a = np.take_along_axis(npv(kk), idx, axis=1)
                 out[kk] = np.ascontiguousarray(a).tobytes()
@@ -457,8 +481,13 @@ class PholdSpanRunner(SpanMeshMixin):
             for kk in PK_KEYS:
                 out[f"r{r}_pk_{kk}"] = np.ascontiguousarray(
                     npv(f"r{r}_pk_{kk}")).tobytes()
+            if var:
+                out[f"r{r}_pk_plen"] = np.ascontiguousarray(
+                    npv(f"r{r}_pk_plen")).tobytes()
 
         out["app_sys"] = npv("app_sys").astype(np.int64).tobytes()
+        if var:
+            out.update(mls.MlCodec(H, self.ML_CAPS)._from_arrays(st))
         return out
 
     # ------------------------------------------------------------------
@@ -477,6 +506,8 @@ class PholdSpanRunner(SpanMeshMixin):
                self.cap_tr, self.tracing, self.family, self.fused,
                self._fabric_params(), self.kern is not None,
                self.mesh, self.exchange_cap, self.pallas_queues)
+        if self.family == 2:
+            key += (self._ml_n, self.ML_CAPS)
         return self._cache_fn(_FN_CACHE, key, lambda: self._build(P))
 
     def _build(self, P: int):
@@ -497,6 +528,10 @@ class PholdSpanRunner(SpanMeshMixin):
         fabric, fab_iv = self._fabric_params()
         FABR = self.FAB_ROWS
         kern = self.kern is not None  # static: stage counters on
+        # memberlist datagrams vary in size: their packets carry a
+        # `plen` column (families 0 and 1 keep the uniform _psize)
+        var = family == 2
+        PKF = PK_KEYS + (("plen",) if var else ())
         hidx = jnp.arange(H, dtype=jnp.int32)
         OOB = jnp.int32(H + 1)  # mode="drop" sink for masked-out lanes
 
@@ -513,6 +548,12 @@ class PholdSpanRunner(SpanMeshMixin):
 
         def mrows(mask):
             return jnp.where(mask, hidx, OOB)
+
+        def psz(st, pk):
+            """wire bytes of each lane's packet"""
+            if var:
+                return pk["plen"].astype(jnp.int64) + 28
+            return st["_psize"]
 
         # -------- primitive helpers ------------------------------
 
@@ -616,7 +657,9 @@ class PholdSpanRunner(SpanMeshMixin):
                  "tr_sip": pk["sip"], "tr_sport": pk["sport"],
                  "tr_dip": pk["dip"], "tr_dport": pk["dport"],
                  "tr_reason": jnp.full(H, reason, jnp.int32),
-                 "tr_owner": hidx}, "tr_n", AB_TRACE)
+                 "tr_owner": hidx,
+                 **({"tr_plen": pk["plen"]} if var else {})},
+                "tr_n", AB_TRACE)
 
         def wake_check(st, changed_bits, time):
             """adjust_status's app_wake fan-out, ordered by wait_seq
@@ -655,12 +698,12 @@ class PholdSpanRunner(SpanMeshMixin):
             st["status"] = jnp.where(mask, nw, cur)
             return wake_check(st, changed, time)
 
-        def bucket_try(st, r, now, mask):
+        def bucket_try(st, r, now, mask, size):
             bal = st[f"r{r}_bal"]
             nxt = st[f"r{r}_next"]
             bal3, nxt2, ok = bucket_step(
                 bal, nxt, st[f"r{r}_refill"], st[f"r{r}_cap"],
-                st[f"r{r}_unlimited"] == 1, st["_psize"], now)
+                st[f"r{r}_unlimited"] == 1, size, now)
             st = dict(st)
             st[f"r{r}_bal"] = jnp.where(mask, bal3, bal)
             st[f"r{r}_next"] = jnp.where(mask, nxt2, nxt)
@@ -678,16 +721,17 @@ class PholdSpanRunner(SpanMeshMixin):
                 pos = st["sq_pos"] % S
                 pk = {kk: jnp.where(use_pend, st[f"r1_pk_{kk}"],
                                     st[f"sq_{kk}"][hidx, pos])
-                      for kk in PK_KEYS}
+                      for kk in PKF}
             else:
                 src_avail = mask & (st["cq_len"] > st["cq_pos"])
                 pos = st["cq_pos"] % C
                 pk = {kk: jnp.where(use_pend, st[f"r2_pk_{kk}"],
                                     st[f"cq_{kk}"][hidx, pos])
-                      for kk in PK_KEYS}
+                      for kk in PKF}
                 enq = st["cq_enq"][hidx, pos]
             pop = mask & ~use_pend & src_avail
             none = mask & ~use_pend & ~src_avail
+            size = psz(st, pk)
 
             st = dict(st)
             st[f"r{r}_pk_valid"] = jnp.where(use_pend, 0,
@@ -697,7 +741,7 @@ class PholdSpanRunner(SpanMeshMixin):
                 st["sq_pos"] = jnp.where(pop, st["sq_pos"] + 1,
                                          st["sq_pos"])
                 st["send_bytes"] = jnp.where(
-                    pop, st["send_bytes"] - st["_psize"], st["send_bytes"])
+                    pop, st["send_bytes"] - size, st["send_bytes"])
                 st["queued"] = jnp.where(
                     pop, (st["sq_len"] > st["sq_pos"]).astype(jnp.int32),
                     st["queued"])
@@ -711,7 +755,7 @@ class PholdSpanRunner(SpanMeshMixin):
                 st["eth_psent"] = jnp.where(pop, st["eth_psent"] + 1,
                                             st["eth_psent"])
                 st["eth_bsent"] = jnp.where(
-                    pop, st["eth_bsent"] + st["_psize"], st["eth_bsent"])
+                    pop, st["eth_bsent"] + size, st["eth_bsent"])
                 st = tr_append(st, pop, now, TR_SND, pk, RSN_NONE)
             else:
                 # full CoDel (codel_pop twin, netplane.cpp): one
@@ -721,7 +765,7 @@ class PholdSpanRunner(SpanMeshMixin):
                 st["cq_pos"] = jnp.where(pop, st["cq_pos"] + 1,
                                          st["cq_pos"])
                 st["codel_bytes"] = jnp.where(
-                    pop, st["codel_bytes"] - st["_psize"],
+                    pop, st["codel_bytes"] - size,
                     st["codel_bytes"])
                 # dequeue_raw's ok/first_above law (pallas_queues)
                 quiet, above, arm, cok, fa_new = codel_head(
@@ -813,7 +857,7 @@ class PholdSpanRunner(SpanMeshMixin):
                     codel_drop, st["codel_dropped"] + 1,
                     st["codel_dropped"])
                 st["codel_drop_bytes"] = jnp.where(
-                    codel_drop, st["codel_drop_bytes"] + st["_psize"],
+                    codel_drop, st["codel_drop_bytes"] + size,
                     st["codel_drop_bytes"])
                 st["app_pkts_dropped"] = jnp.where(
                     codel_drop, st["app_pkts_dropped"] + 1,
@@ -827,7 +871,7 @@ class PholdSpanRunner(SpanMeshMixin):
                 pop = pop & ~codel_drop
 
             has_pkt = use_pend | pop
-            st, ok, when = bucket_try(st, r, now, has_pkt)
+            st, ok, when = bucket_try(st, r, now, has_pkt, size)
             throttled = has_pkt & ~ok
             st = dict(st)
             st[f"r{r}_stalls"] = st[f"r{r}_stalls"] + throttled
@@ -835,7 +879,7 @@ class PholdSpanRunner(SpanMeshMixin):
                                             st[f"r{r}_pending"])
             st[f"r{r}_pk_valid"] = jnp.where(throttled, 1,
                                              st[f"r{r}_pk_valid"])
-            for kk in PK_KEYS:
+            for kk in PKF:
                 st[f"r{r}_pk_{kk}"] = jnp.where(throttled, pk[kk],
                                                 st[f"r{r}_pk_{kk}"])
             st, sq = draw_seq(st, throttled)
@@ -845,7 +889,7 @@ class PholdSpanRunner(SpanMeshMixin):
             fwd = has_pkt & ok
             st[f"r{r}_fwd_pkts"] = st[f"r{r}_fwd_pkts"] + fwd
             st[f"r{r}_fwd_bytes"] = st[f"r{r}_fwd_bytes"] \
-                + jnp.where(fwd, st["_psize"], jnp.int64(0))
+                + jnp.where(fwd, size, jnp.int64(0))
             if r == 1:
                 # device_push(dev=2): cross-host send into the outbox
                 dslot = jnp.minimum(
@@ -882,14 +926,15 @@ class PholdSpanRunner(SpanMeshMixin):
                     {"out_src": hidx, "out_dst": dst, "out_seq": sq,
                      "out_pseq": pk["pseq"], "out_sip": pk["sip"],
                      "out_sport": pk["sport"], "out_dip": pk["dip"],
-                     "out_dport": pk["dport"], "out_t": now}, "out_n",
-                    AB_OUT)
+                     "out_dport": pk["dport"], "out_t": now,
+                     **({"out_plen": pk["plen"]} if var else {})},
+                    "out_n", AB_OUT)
             else:
                 # iface_receive -> udp_push_in
                 st["eth_precv"] = jnp.where(fwd, st["eth_precv"] + 1,
                                             st["eth_precv"])
                 st["eth_brecv"] = jnp.where(
-                    fwd, st["eth_brecv"] + st["_psize"], st["eth_brecv"])
+                    fwd, st["eth_brecv"] + size, st["eth_brecv"])
                 wrong = fwd & ((pk["dport"] != st["m_port"])
                                | (st["sock_closed"] == 1))
                 st["app_pkts_dropped"] = jnp.where(
@@ -900,7 +945,7 @@ class PholdSpanRunner(SpanMeshMixin):
                 st = tr_append(st, wrong, now, TR_DRP, pk, RSN_NOSOCK)
                 st = dict(st)
                 deliver = fwd & ~wrong
-                full = deliver & (st["recv_bytes"] + st["_psize"]
+                full = deliver & (st["recv_bytes"] + size
                                   > st["recv_max"])
                 st["app_pkts_dropped"] = jnp.where(
                     full, st["app_pkts_dropped"] + 1,
@@ -915,13 +960,13 @@ class PholdSpanRunner(SpanMeshMixin):
                 st = dict(st)
                 tail = st["rq_len"] % R
                 rows = mrows(good)
-                for kk in PK_KEYS:
+                for kk in PKF:
                     st[f"rq_{kk}"] = scatter_set(st[f"rq_{kk}"],
                                                  (rows, tail), pk[kk])
                 st["rq_len"] = jnp.where(good, st["rq_len"] + 1,
                                          st["rq_len"])
                 st["recv_bytes"] = jnp.where(
-                    good, st["recv_bytes"] + st["_psize"],
+                    good, st["recv_bytes"] + size,
                     st["recv_bytes"])
                 st = set_status(st, jnp.uint32(S_READABLE),
                                 jnp.uint32(0), good, now)
@@ -1153,6 +1198,9 @@ class PholdSpanRunner(SpanMeshMixin):
             """C_M_STEP / C_S_STEP micro-op."""
             if family == 1:
                 return op_step_mesh(st, mask, is_seed)
+            if family == 2:
+                # one memberlist member per host, no seeder thread
+                return st if is_seed else ml_step(st, mask)
             state_k = "s_state" if is_seed else "m_state"
             st = dict(st)
             restart = mask & (st[state_k] == 1)
@@ -1178,9 +1226,9 @@ class PholdSpanRunner(SpanMeshMixin):
             return st
 
         def op_stage2(st, mask):
-            """C_M_RECV / C_S_POST micro-op (phold only; mesh
-            steppers never use these continuations)."""
-            if family == 1:
+            """C_M_RECV / C_S_POST micro-op (phold only; mesh and
+            memberlist steppers never use these continuations)."""
+            if family in (1, 2):
                 return st
             now = st["now"]
             m_recv = mask & (st["cont"] == C_M_RECV)
@@ -1224,6 +1272,21 @@ class PholdSpanRunner(SpanMeshMixin):
             st["cont"] = jnp.where(more, C_IDLE, st["cont"])
             return st
 
+        if family == 2:
+            from types import SimpleNamespace
+            ml_step, ml_check = mls.build_ml_step(
+                jax, jnp, H, self._ml_n, self.ML_CAPS, SimpleNamespace(
+                    mark_abort=mark_abort, draw_seq=draw_seq,
+                    th_push=th_push, set_status=set_status,
+                    ks_count=ks_count, stage=stage, mrows=mrows,
+                    scatter_set=scatter_set, hidx=hidx, R=R, S=S, C=C,
+                    I=I, AB_STRUCT=AB_STRUCT, TK_APP=TK_APP,
+                    S_READABLE=S_READABLE, S_WRITABLE=S_WRITABLE,
+                    ASYS_SENDTO=ASYS_SENDTO, ASYS_RECVFROM=ASYS_RECVFROM,
+                    KS_PROBE=KS_PROBE, KS_GOSSIP=KS_GOSSIP,
+                    KS_MERGE=KS_MERGE, PKF=PKF, C_IDLE=C_IDLE,
+                    C_R1=C_R1, C_M_STEP=C_M_STEP))
+
         # -------- micro-op: event pop ----------------------------
 
         def next_event_time(st):
@@ -1262,7 +1325,8 @@ class PholdSpanRunner(SpanMeshMixin):
             # with an rtr-limit breadcrumb (run_until twin).
             arr = due & pick_ib
             st["ib_pos"] = jnp.where(arr, pos + 1, pos)
-            pk_arr = {kk: st[f"ib_{kk}"][hidx, safe] for kk in PK_KEYS}
+            pk_arr = {kk: st[f"ib_{kk}"][hidx, safe] for kk in PKF}
+            size = psz(st, pk_arr)
             arr_f = arr & nic_dead
             st["app_pkts_dropped"] = jnp.where(
                 arr_f, st["app_pkts_dropped"] + 1,
@@ -1280,7 +1344,7 @@ class PholdSpanRunner(SpanMeshMixin):
             st["codel_enq_pkts"] = jnp.where(
                 arr, st["codel_enq_pkts"] + 1, st["codel_enq_pkts"])
             st["codel_enq_bytes"] = jnp.where(
-                arr, st["codel_enq_bytes"] + st["_psize"],
+                arr, st["codel_enq_bytes"] + size,
                 st["codel_enq_bytes"])
             limit_full = arr & (st["cq_len"] - st["cq_pos"]
                                 >= CODEL_HARD_LIMIT)
@@ -1293,7 +1357,7 @@ class PholdSpanRunner(SpanMeshMixin):
                 limit_full, st["codel_dropped"] + 1,
                 st["codel_dropped"])
             st["codel_drop_bytes"] = jnp.where(
-                limit_full, st["codel_drop_bytes"] + st["_psize"],
+                limit_full, st["codel_drop_bytes"] + size,
                 st["codel_drop_bytes"])
             st["app_pkts_dropped"] = jnp.where(
                 limit_full, st["app_pkts_dropped"] + 1,
@@ -1308,7 +1372,7 @@ class PholdSpanRunner(SpanMeshMixin):
             st = dict(st)
             tail = st["cq_len"] % C
             rows = mrows(arr)
-            for kk in PK_KEYS:
+            for kk in PKF:
                 st[f"cq_{kk}"] = scatter_set(st[f"cq_{kk}"], (rows, tail),
                                              st[f"ib_{kk}"][hidx, safe])
             st["cq_enq"] = scatter_set(st["cq_enq"], (rows, tail), et)
@@ -1321,7 +1385,7 @@ class PholdSpanRunner(SpanMeshMixin):
                               jnp.int64),
                           jnp.int64(0)))
             st["codel_bytes"] = jnp.where(
-                arr, st["codel_bytes"] + st["_psize"], st["codel_bytes"])
+                arr, st["codel_bytes"] + size, st["codel_bytes"])
             go2 = arr & (st["r2_pending"] == 0)
             st["cont"] = jnp.where(go2, C_R2, st["cont"])
             st["then"] = jnp.where(go2, C_IDLE, st["then"])
@@ -1402,10 +1466,18 @@ class PholdSpanRunner(SpanMeshMixin):
                                       window_end)
                     st = op_pop_event(st, st["cont"] == C_IDLE,
                                       window_end)
-                st = guard(st, st["cont"] == C_M_STEP,
-                           lambda s, m: op_step(s, m, False), KS_STEP)
-                st = guard(st, st["cont"] == C_S_STEP,
-                           lambda s, m: op_step(s, m, True), KS_STEP)
+                if family == 2:
+                    # unguarded: the memberlist stepper writes the
+                    # (H, N) view (see ops/memberlist_span.py op_ml)
+                    with stage(KS_STEP):
+                        m = st["cont"] == C_M_STEP
+                        st = ks_count(st, KS_STEP, m)
+                        st = op_step(st, m, False)
+                else:
+                    st = guard(st, st["cont"] == C_M_STEP,
+                               lambda s, m: op_step(s, m, False), KS_STEP)
+                    st = guard(st, st["cont"] == C_S_STEP,
+                               lambda s, m: op_step(s, m, True), KS_STEP)
                 # Two relay passes per iteration: the second pass lets
                 # a drain that just emptied its source take the
                 # exhausted-exit in the same iteration (streaming
@@ -1505,7 +1577,9 @@ class PholdSpanRunner(SpanMeshMixin):
                             ("tr_dport", st["out_dport"]),
                             ("tr_reason",
                              jnp.full(O, rsn, jnp.int32)),
-                            ("tr_owner", src)):
+                            ("tr_owner", src)) + (
+                                (("tr_plen", st["out_plen"]),)
+                                if var else ()):
                         st[key] = scatter_set(st[key], slot, v)
                     tot = nt_ + miss.sum()
                     st["tr_n"] = tot
@@ -1529,10 +1603,12 @@ class PholdSpanRunner(SpanMeshMixin):
             ib_time = compact(st["ib_time"], I64_MAX)
             ib_src = compact(st["ib_src"], 0)
             ib_seq = compact(st["ib_seq"], I64_MAX)
-            ib_pk = {kk: compact(st[f"ib_{kk}"], 0) for kk in PK_KEYS}
+            ib_pk = {kk: compact(st[f"ib_{kk}"], 0) for kk in PKF}
             new = {"srchost": src, "pseq": st["out_pseq"],
                    "sip": st["out_sip"], "sport": st["out_sport"],
                    "dip": st["out_dip"], "dport": st["out_dport"]}
+            if var:
+                new["plen"] = st["out_plen"]
             d_dst, d_time, d_src, d_seq = dst, deliver, src, \
                 st["out_seq"]
             d_pk, d_keep, DN = new, keep, O
@@ -1550,7 +1626,7 @@ class PholdSpanRunner(SpanMeshMixin):
                 cols = {"dst": (dst, H), "time": (deliver, I64_MAX),
                         "src": (src, 0), "seq": (st["out_seq"],
                                                  I64_MAX)}
-                cols.update({kk: (new[kk], 0) for kk in PK_KEYS})
+                cols.update({kk: (new[kk], 0) for kk in PKF})
                 ex, over = stage(keep, dst // hs, cols)
                 # Observatory: the exchange is a per-ROUND stage —
                 # lanes are packets staged through the cross-shard
@@ -1560,7 +1636,7 @@ class PholdSpanRunner(SpanMeshMixin):
                 st = dict(st)
                 d_dst, d_time = ex["dst"], ex["time"]
                 d_src, d_seq = ex["src"], ex["seq"]
-                d_pk = {kk: ex[kk] for kk in PK_KEYS}
+                d_pk = {kk: ex[kk] for kk in PKF}
                 d_keep, DN = ex["dst"] < H, SE
             # stable per-destination rank in delivery order
             seg = jnp.where(d_keep, d_dst, H)
@@ -1580,7 +1656,7 @@ class PholdSpanRunner(SpanMeshMixin):
             ib_time = scatter_set(ib_time, (rows, slot), d_time)
             ib_src = scatter_set(ib_src, (rows, slot), d_src)
             ib_seq = scatter_set(ib_seq, (rows, slot), d_seq)
-            for kk in PK_KEYS:
+            for kk in PKF:
                 ib_pk[kk] = scatter_set(ib_pk[kk], (rows, slot), d_pk[kk])
             add = jnp.zeros(H, jnp.int32).at[rows].add(1, mode="drop")
             sort_idx = jnp.lexsort((ib_seq, ib_src, ib_time), axis=1)
@@ -1588,7 +1664,7 @@ class PholdSpanRunner(SpanMeshMixin):
             st["ib_time"] = take(ib_time, sort_idx, axis=1)
             st["ib_src"] = take(ib_src, sort_idx, axis=1)
             st["ib_seq"] = take(ib_seq, sort_idx, axis=1)
-            for kk in PK_KEYS:
+            for kk in PKF:
                 st[f"ib_{kk}"] = take(ib_pk[kk], sort_idx, axis=1)
             st["ib_pos"] = jnp.zeros(H, jnp.int32)
             st["ib_len"] = rem + add
@@ -1711,7 +1787,8 @@ class PholdSpanRunner(SpanMeshMixin):
                           ("out_sport", jnp.int32),
                           ("out_dip", jnp.uint32),
                           ("out_dport", jnp.int32),
-                          ("out_t", jnp.int64)):
+                          ("out_t", jnp.int64)) + (
+                              (("out_plen", jnp.int32),) if var else ()):
                 st[k] = jnp.zeros(O, dt)
             if tracing:
                 st["tr_n"] = jnp.int64(0)
@@ -1724,7 +1801,8 @@ class PholdSpanRunner(SpanMeshMixin):
                               ("tr_dip", jnp.uint32),
                               ("tr_dport", jnp.int32),
                               ("tr_reason", jnp.int32),
-                              ("tr_owner", jnp.int32)):
+                              ("tr_owner", jnp.int32)) + (
+                                  (("tr_plen", jnp.int32),) if var else ()):
                     st[k] = jnp.zeros(TR, dt)
             if fabric:
                 st["fab_n"] = jnp.int32(0)
@@ -1742,6 +1820,11 @@ class PholdSpanRunner(SpanMeshMixin):
                 st["ks_fires"] = jnp.zeros(KS_N, jnp.int64)
                 st["ks_lanes"] = jnp.zeros(KS_N, jnp.int64)
 
+            if family == 2:
+                # the view's narrow columns widen to 32 bits for the
+                # span: a TPU scatters 32-bit words, and a u8/u16 target
+                # is widened around every write
+                st = mls.widen_view(jnp, st)
             carry = (st, jnp.int64(start), jnp.int64(runahead),
                      jnp.int64(0), jnp.int64(0), jnp.int64(0),
                      jnp.int64(start), jnp.int64(stop),
@@ -1750,17 +1833,20 @@ class PholdSpanRunner(SpanMeshMixin):
             (st, start, runahead, rounds, busy_rounds, packets,
              busy_end, _s, _l, _m, iters) = jax.lax.while_loop(
                 round_cond, round_body, carry)
+            if family == 2:
+                st = mls.narrow_view(jnp, ml_check(st))
             # Only mutated columns go back over the device link: the
             # routing tables, peer lists, and static socket/app config
             # are inputs the host already has, and the span-local
             # outbox was fully consumed by propagate.  The derived
             # chain registers re-derive on every input (out_first
             # stays: the import codec reads it).
-            drop = (RESIDENT_STATIC
+            drop = (RESIDENT_STATIC | mls.RESIDENT_STATIC
+                    | mls.RESIDENT_DERIVED
                     | (RESIDENT_DERIVED - {"out_first"})
                     | {"out_n", "out_src", "out_dst", "out_seq",
                        "out_pseq", "out_sip", "out_sport", "out_dip",
-                       "out_dport", "out_t"})
+                       "out_dport", "out_t", "out_plen"})
             st = {k: v for k, v in st.items()
                   if not k.startswith("_") and k not in drop}
             return (st, start, runahead, rounds, busy_rounds, packets,
@@ -1776,10 +1862,17 @@ class PholdSpanRunner(SpanMeshMixin):
         """Fresh engine export -> state dict, or the int/None
         eligibility verdict passed through from span_export_phold."""
         w = self.wall
+        view = None
         with Span(w, "export"):
             d = self.engine.span_export_phold(
                 self.CAP_I, self.CAP_T, self.CAP_R, self.CAP_S,
-                self.CAP_C, self.CAP_P)
+                self.CAP_C, self.CAP_P, self.ML_CAPS)
+            if isinstance(d, dict) and \
+                    int(np.frombuffer(d["family"], np.uint8)[0]) == 2:
+                # the members' dense views: the export's largest leg
+                with Span(w, "view-export"):
+                    view = mls.MlCodec(self._H, self.ML_CAPS)._view_to_arrays(
+                        self.engine.span_export_ml_view())
         if d is None or isinstance(d, int):
             return d
         with Span(w, "convert"):
@@ -1788,6 +1881,8 @@ class PholdSpanRunner(SpanMeshMixin):
                 len(v) for v in d.values()
                 if isinstance(v, (bytes, bytearray, memoryview)))
             st = self._to_arrays(d)  # also sets self.family/_pay
+            if view is not None:
+                st.update(view)
             # Cache the static config as committed device arrays: the
             # host->device transfer of the largest columns (peers is
             # H x P) is paid once per export, and every later dispatch
@@ -1795,7 +1890,10 @@ class PholdSpanRunner(SpanMeshMixin):
             # (device_put on an already-placed array is a no-op).
             import jax
             self._static_cols = {
-                k: self._put_static(jax, st[k]) for k in RESIDENT_STATIC}
+                k: self._put_static(jax, st[k])
+                for k in RESIDENT_STATIC | mls.RESIDENT_STATIC if k in st}
+            if self.family == 2:
+                self._ml_n = int(st["ml_ips"].shape[0])
             st.update(self._static_cols)
         return st
 
@@ -1816,6 +1914,8 @@ class PholdSpanRunner(SpanMeshMixin):
             st[k] = z
         st["park_ctr"] = jnp.maximum(st["m_waitseq"],
                                      st["s_waitseq"]) + 1
+        if self.family == 2:
+            st.update(mls.ml_derived(np, self._H, self.ML_CAPS))
         return st
 
     def _clamp_mr(self, mr: int | None) -> int:
@@ -2036,7 +2136,9 @@ class PholdSpanRunner(SpanMeshMixin):
                 "dip": st_np["tr_dip"][:n].astype(np.uint32).tobytes(),
                 "dport": st_np["tr_dport"][:n].astype(
                     np.int32).tobytes(),
-                "size": np.full(n, self._pay, np.int64).tobytes(),
+                "size": (st_np["tr_plen"][:n].astype(np.int64).tobytes()
+                         if self.family == 2 else
+                         np.full(n, self._pay, np.int64).tobytes()),
                 "reason": st_np["tr_reason"][:n].astype(
                     np.uint8).tobytes(),
                 "owner": st_np["tr_owner"][:n].astype(
@@ -2053,6 +2155,12 @@ class PholdSpanRunner(SpanMeshMixin):
             self.import_bytes += sum(
                 len(v) for v in back.values()
                 if isinstance(v, (bytes, bytearray, memoryview)))
+            if self.family == 2:
+                with Span(w, "view-import"):
+                    view = mls.MlCodec(self._H,
+                                       self.ML_CAPS)._view_from_arrays(st_np)
+                    self.import_bytes += sum(map(len, view.values()))
+                    self.engine.span_import_ml_view(view)
             self.engine.span_import_phold(
                 back, self.CAP_I, self.CAP_T, self.CAP_R, self.CAP_S,
                 self.CAP_C, self.CAP_P, traces)

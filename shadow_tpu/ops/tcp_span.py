@@ -147,7 +147,7 @@ KS_INET_OUT = 8
 KS_ARM = 9
 KS_TIMERS = 10
 KS_EXCHANGE = 11
-KS_N = 12
+KS_N = 15
 
 # Telemetry sample fields (trace/events.py TEL_REC order after the
 # identity header) -> the SoA column each samples.

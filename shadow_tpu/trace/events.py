@@ -471,6 +471,9 @@ def iter_fct_records(fct_bytes: bytes):
 #   timers      timer handling (phold: inline timer pops; tcp: op_tmr)
 #   exchange    sharded cross-shard staging hop (per round, lanes =
 #               packets staged — a per-round stage, not a micro-op)
+#   probe       memberlist protocol timers and probing (phold family 2)
+#   gossip      memberlist gossip sends (phold family 2)
+#   merge       memberlist state updates applied (phold family 2)
 KS_POP = 0
 KS_STEP = 1
 KS_CODEL = 2
@@ -483,7 +486,10 @@ KS_INET_OUT = 8
 KS_ARM = 9
 KS_TIMERS = 10
 KS_EXCHANGE = 11
-KS_N = 12
+KS_PROBE = 12
+KS_GOSSIP = 13
+KS_MERGE = 14
+KS_N = 15
 
 # Order mirrors the KS_* values above AND the C++ KS_NAMES table
 # (pass 1 checks both directions).
@@ -500,6 +506,9 @@ KS_NAMES = (
     "arm",
     "timers",
     "exchange",
+    "probe",
+    "gossip",
+    "merge",
 )
 assert len(KS_NAMES) == KS_N
 
@@ -513,8 +522,8 @@ assert len(KS_NAMES) == KS_N
 #     int64   trips      micro-loop while-iterations in this span
 #     int64[KS_N]        fires per stage
 #     int64[KS_N]        active-lane sums per stage
-KS_REC_BYTES = 224
-KS_REC = struct.Struct("<qiiqq24q")
+KS_REC_BYTES = 272
+KS_REC = struct.Struct("<qiiqq30q")
 assert KS_REC.size == KS_REC_BYTES
 
 

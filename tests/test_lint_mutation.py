@@ -606,3 +606,41 @@ def test_async_hazard_bites_on_real_dispatch_loop(tmp_path):
     clean = determinism.check(ROOT, paths=[path])
     assert all(x.rule != "async-hazard" for x in clean), \
         [x.render() for x in clean]
+
+
+def test_memberlist_constant_drift_is_caught(cpp_text):
+    # the engine's probe timeout against the device stepper's twin
+    mutated = _mutate(cpp_text, "ML_PROBE_TO = 500000000",
+                      "ML_PROBE_TO = 500000001")
+    v = twin_constants.check(ROOT, cpp_text=mutated)
+    assert any("ML_PROBE_TO" in x.message for x in v), \
+        [x.render() for x in v]
+
+
+def test_unregistered_memberlist_enum_fails_closed(cpp_text):
+    mutated = _mutate(cpp_text, "MLC_UPD_DROPPED, MLC_N",
+                      "MLC_UPD_DROPPED, MLC_EXTRA, MLC_N")
+    v = twin_constants.check(ROOT, cpp_text=mutated)
+    assert any("MLC_EXTRA" in x.message for x in v), \
+        [x.render() for x in v]
+
+
+def test_memberlist_column_rename_is_caught(cpp_text):
+    # ml_export's broadcast-queue column renamed: the codec's read of
+    # it becomes a phantom and the export's column dead traffic
+    mutated = _mutate(cpp_text, 'put("ml_bq_tx", bytes_vec(bq_tx));',
+                      'put("ml_bq_txx", bytes_vec(bq_tx));')
+    v = soa_layout.check(ROOT, cpp_text=mutated)
+    msgs = [x.message for x in v]
+    assert any("ml_bq_txx" in m for m in msgs), msgs
+    assert any("'ml_bq_tx'" in m and "[memberlist]" in m for m in msgs), \
+        msgs
+
+
+def test_memberlist_view_dtype_drift_is_caught(cpp_text):
+    mutated = _mutate(cpp_text,
+                      "std::vector<uint16_t> order(H * N, 0);",
+                      "std::vector<uint32_t> order(H * N, 0);")
+    v = soa_layout.check(ROOT, cpp_text=mutated)
+    assert any("ml_order" in x.message and "[memberlist-view]"
+               in x.message for x in v), [x.render() for x in v]

@@ -137,6 +137,69 @@ def test_phold_span_kernel_compiles(monkeypatch, one_chip):
                        + "; ".join(pairs[:10]))
 
 
+def _capture_memberlist_span(monkeypatch, n):
+    """The span runner and its first dispatch's arguments for a pool
+    of `n` memberlist members, from a forced-device run on the CPU."""
+    from shadow_tpu.core.config import ConfigOptions
+    from shadow_tpu.core.manager import Manager
+    from shadow_tpu.ops import span_mesh
+    got = {}
+
+    def grab(self, fn, *args):
+        got.update(runner=self, args=args)
+        raise _Captured()
+    monkeypatch.setattr(span_mesh.SpanMeshMixin, "_span_call", grab)
+    hosts = {f"m{j:05d}": {"network_node_id": 0, "processes": [{
+        "path": "memberlist", "args": ["7946", str(j), "m%05d", str(n),
+                                       "2718281828"],
+        "start_time": "100ms", "expected_final_state": "running"}]}
+        for j in range(n)}
+    cfg = ConfigOptions.from_dict({
+        "general": {"stop_time": "0.3s", "seed": 13},
+        "network": {"graph": {"type": "1_gbit_switch"}},
+        "experimental": {"scheduler": "tpu", "native_dataplane": "on",
+                         "tpu_device_spans": "force"},
+        "hosts": hosts})
+    with pytest.raises(_Captured):
+        Manager(cfg).run()
+    return got["runner"], got["args"]
+
+
+def test_memberlist_span_kernel_compiles(monkeypatch, one_chip):
+    """The memberlist span kernel (family 2) at 10,000 members, each
+    with its view of all 10,000: captured at 96 members and rebuilt at
+    full width.  Every dimension of 96 (hosts, members) grows to
+    10,000, and the size-indexed tables (n + 1 entries, three per n)
+    grow with it.  It fits a v5e and holds no tuple-result scatter."""
+    import jax
+    small = 96
+    runner, args = _capture_memberlist_span(monkeypatch, small)
+    assert runner.family == 2
+    runner._H = HOSTS
+    runner._ml_n = HOSTS
+    runner.cap_out = max(512, 16 * HOSTS)
+    runner.cap_tr = max(1 << 14, 64 * HOSTS)
+    fn = runner._build(runner._static_cols["peers"].shape[1])
+    grow = {small: HOSTS, small + 1: HOSTS + 1,
+            3 * (small + 1): 3 * (HOSTS + 1)}
+
+    def widen(v):
+        if not hasattr(v, "dtype"):
+            return v
+        shape = tuple(grow.get(d, d) for d in np.shape(v))
+        return jax.ShapeDtypeStruct(shape, v.dtype, sharding=one_chip)
+    st = {k: widen(v) for k, v in args[0].items()}
+    assert st["ml_vinc"].shape == (HOSTS, HOSTS)
+    compiled = fn.lower(st, *map(widen, args[1:])).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        + mem.temp_size_in_bytes < 16e9, "does not fit a v5e's 16 GB"
+    pairs = [line for line in compiled.as_text().splitlines()
+             if re.match(r"\s*(?:ROOT\s+)?%?(\S+)\s*=\s*\([^=]*\)"
+                         r"\s*scatter\(", line)]
+    assert not pairs, f"{len(pairs)} tuple-result scatters"
+
+
 @pytest.mark.parametrize("kernel", ["bucket_step", "codel_head"])
 def test_pallas_queue_kernel_refused_for_int64(monkeypatch, one_chip,
                                                kernel):
